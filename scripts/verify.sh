@@ -79,7 +79,7 @@ from repro.kernels.rss_scan_agg.ref import rss_scan_agg_chunked_ref
 # chunked kernel == segment-sum oracle per chunk; device tree fold ==
 # flat-lane host fold (non-divisible G, TAG_PAD, gid -1, empty groups)
 rng = np.random.default_rng(1)
-for P, K, E in [(24, 3, 16), (72, 4, 8)]:
+for P, K, E in [(24, 3, 16), (1096, 4, 8)]:
     data = np.zeros((P, K, E), np.int32)
     data[:, :, 0] = rng.integers(-1, 4, (P, K))
     data[:, :, 1] = rng.integers(-99, 99, (P, K))
@@ -90,12 +90,12 @@ for P, K, E in [(24, 3, 16), (72, 4, 8)]:
         mem = jnp.asarray(np.sort(rng.choice(np.arange(1, 50), size=7,
                                              replace=False)), jnp.int32)
         args = (data, ts, gid, mem, 21, 1, 0, 50)
-        chunks = rss_scan_agg_chunked(*args, n_groups=G, rows_per_step=2,
+        chunks = rss_scan_agg_chunked(*args, n_groups=G, rows_per_step=8,
                                       fold_chunks=2)
         np.testing.assert_array_equal(
             np.asarray(chunks),
             np.asarray(rss_scan_agg_chunked_ref(
-                *args, n_groups=G, rows_per_step=2, fold_chunks=2)))
+                *args, n_groups=G, rows_per_step=8, fold_chunks=2)))
         flat = rss_scan_agg_grouped(*args, n_groups=G)
         assert fold_group_partials(chunks) == fold_group_partials(flat)
         np.testing.assert_array_equal(np.asarray(tree_fold_partials(chunks)),

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU).
+"""Pallas TPU kernels (compiled on a TPU backend, interpreted elsewhere).
 
 version_gather   — SI-V snapshot visibility gather (the paper's hot spot)
 rss_gather       — RSS set-membership visibility gather (previous-version read)
@@ -8,9 +8,9 @@ flash_attention  — causal/SWA GQA prefill-train attention
 decode_attention — one-token GQA decode over ring caches
 wkv_scan         — RWKV6 data-dependent-decay recurrence
 
-Every op's `interpret` argument defaults to the REPRO_INTERPRET environment
-switch (`repro.kernels.config`): =1 interpret mode (CPU validation, the
-default), =0 compiled for TPU — the one-flag flip for hardware runs.
+Every op's `interpret` argument defaults to None, resolved from the backend
+at call time (`repro.kernels.config`): compiled on TPU, interpret mode on
+CPU.  An explicit `interpret=` wins.
 """
 
 from .config import default_interpret, resolve_interpret

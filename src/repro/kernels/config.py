@@ -1,35 +1,27 @@
-"""One execution-mode switch for every Pallas kernel op.
+"""How every Pallas kernel op picks its execution mode.
 
-All kernel ops (`repro.kernels.*.ops`) default their `interpret` argument to
-None, which resolves through `resolve_interpret` against the REPRO_INTERPRET
-environment variable:
-
-    REPRO_INTERPRET=1 (default)  — Pallas interpret mode: the kernels execute
-                                   on CPU, validating the exact kernel code
-                                   path in every test/CI run.
-    REPRO_INTERPRET=0            — compiled mode for real TPU hardware: the
-                                   one-flag flip for the roofline-validating
-                                   benchmark run (ROADMAP "TPU-compiled
-                                   benchmark run").
-
-An explicit `interpret=True/False` at a call site always wins over the
-environment, so tests can pin a mode regardless of how CI is configured.
+Kernel ops take `interpret: bool | None = None`.  None resolves from the
+backend at call time: compiled when `jax.default_backend() == "tpu"`,
+Pallas interpret mode (the kernel bodies executed on CPU) otherwise.  No
+environment variable or import-time state can make a chip run
+interpreted.  An explicit `interpret=True/False` always wins, so tests
+can pin a mode.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
-_FALSE = ("0", "false", "no", "off")
+import jax
 
 
 def default_interpret() -> bool:
-    """The environment-configured Pallas execution mode (True = interpret)."""
-    return os.environ.get("REPRO_INTERPRET", "1").strip().lower() not in _FALSE
+    """The backend's Pallas execution mode: False (compiled) on a TPU
+    backend, True (interpret) on any other."""
+    return jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Resolve an op's `interpret` argument: None defers to REPRO_INTERPRET;
+    """Resolve an op's `interpret` argument: None defers to the backend;
     an explicit boolean wins."""
     return default_interpret() if interpret is None else bool(interpret)

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..config import resolve_interpret
+
 
 def _kernel(mem_ref, floor_ref, ts_ref, data_ref, out_ref):
     ts = ts_ref[...]                           # [BP, K] int32
@@ -66,9 +68,10 @@ def _kernel(mem_ref, floor_ref, ts_ref, data_ref, out_ref):
 def rss_gather(data: jax.Array, ts: jax.Array, member_ts: jax.Array,
                floor: jax.Array | int = 0,
                *, block_pages: int = 8, block_elems: int = 512,
-               interpret: bool = True) -> jax.Array:
-    """Pallas RSS membership read.  interpret=True executes on CPU
-    (validation); interpret=False targets TPU."""
+               interpret: bool | None = None) -> jax.Array:
+    """Pallas RSS membership read.  interpret=None resolves from the
+    backend (`repro.kernels.config`): compiled on TPU, interpreted
+    elsewhere."""
     P, K, E = data.shape
     assert ts.shape == (P, K)
     bp = min(block_pages, P)
@@ -94,5 +97,5 @@ def rss_gather(data: jax.Array, ts: jax.Array, member_ts: jax.Array,
         ],
         out_specs=pl.BlockSpec((bp, be), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((P, E), data.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(mem, floor_tile, ts, data)

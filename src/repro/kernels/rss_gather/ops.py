@@ -6,7 +6,6 @@ from typing import Optional
 
 import jax
 
-from ..config import resolve_interpret
 from .kernel import rss_gather
 from .ref import rss_gather_ref
 
@@ -19,11 +18,9 @@ def snapshot_read_members(store: dict, member_ts, floor=0, *,
     member_ts is the sorted int32 array of member commit timestamps ABOVE
     the snapshot floor (the commit-seq image of an exported `RssSnapshot`:
     `snap.member_seqs` + `snap.floor_seq`); every version at ts <= floor is
-    a floor-covered member's.  interpret defaults to the REPRO_INTERPRET
-    switch (`repro.kernels.config`): interpret mode validates the kernel
-    code path on CPU; REPRO_INTERPRET=0 (or interpret=False) compiles for
-    TPU."""
+    a floor-covered member's.  interpret=None resolves from the backend
+    (`repro.kernels.config`): compiled on TPU, interpret mode elsewhere."""
     if not use_kernel:
         return rss_gather_ref(store["data"], store["ts"], member_ts, floor)
     return rss_gather(store["data"], store["ts"], member_ts, floor,
-                      interpret=resolve_interpret(interpret))
+                      interpret=interpret)

@@ -25,10 +25,10 @@ Contract (matches ref.py):
     threshold scalar           predicate bound shared by the thresholded
                                lanes (count_below / count_above /
                                sum_below)
-    out       [P/BP, 128] int32  ONE PARTIAL ROW PER GRID BLOCK, lanes
-                               0..6 = sum, count, count_below, min
-                               (INT32_MAX when the block matched nothing),
-                               max (INT32_MIN), count_above, sum_below
+    out       [P/BP, 7] int32  ONE PARTIAL ROW PER GRID BLOCK: sum, count,
+                               count_below, min (INT32_MAX when the block
+                               matched nothing), max (INT32_MIN),
+                               count_above, sum_below
 
 Visibility is the `rss_gather` protocol verbatim (ts <= floor OR ts in the
 member array, newest wins, ties toward the lowest slot).  Each grid step
@@ -37,8 +37,13 @@ folds the rows ON HOST in arbitrary-precision Python ints.  Deliberate
 overflow discipline: device arithmetic stays int32 (TPU-native), so a
 whole-scan sum can exceed int32 without wrapping — only a single BP-page
 block's partial must fit (|field| max < 2**31/BP per block; `ops` enforces
-the bound host-side and shrinks BP when violated), keeping the fused
-result bitwise equal to the per-key Python oracle.
+the bound host-side and takes an exact fallback when violated), keeping
+the fused result bitwise equal to the per-key Python oracle.
+
+TPU tiling: every block's last two dims are multiples of (8, 128) or the
+array's own dims, so BP is a multiple of 8 and every output block holds
+at least 8 rows.  The scalar aggregate is the flat grouped kernel with
+one group (its [8, 128] tile carries the row in sublane 0).
 
 Arithmetic intensity stays ~1 FLOP per K bytes read, but the fused path
 writes P/BP partial rows instead of P·E gathered elements and skips the
@@ -57,13 +62,13 @@ counts, decays past G ~ 8-16.  Per-group kernel params (`group_params
 [G, 3] = tag_main, tag_alt, threshold` rows) let ONE launch serve lanes
 drawn from different plans/configs — the whole-batch fusion substrate.
 
-`rss_select` + `rss_scan_agg_chunked` — CHUNKED TWO-STAGE: stage one
-resolves visibility ONCE and packs (tag, field, gid) for 64 pages per
-row into a [rows, 256] intermediate (lanes 0-63 tag, 64-127 field,
-128-191 gid, 192-255 zero); stage two re-reduces that packed stream over
-a TILED group axis — grid (G/G_tile, chunks, steps) where each step
-accumulates `rows_per_step` rows into its chunk's [G_tile, 128] partial
-tile via `@pl.when` revisits.  VMEM per step is bounded by G_tile, not
+`rss_scan_agg_chunked` — CHUNKED TWO-STAGE: stage one resolves
+visibility ONCE and packs (tag, field, gid) for 64 pages per row into a
+[rows, 256] intermediate (lanes 0-63 tag, 64-127 field, 128-191 gid,
+192-255 zero), 8 rows (512 pages) per grid step; stage two re-reduces
+that packed stream over a TILED group axis — grid (G/G_tile, chunks,
+steps) where each step accumulates `rows_per_step` rows into its chunk's
+[G_tile, 128] partial tile via `@pl.when` revisits.  VMEM per step is bounded by G_tile, not
 G, so G=64..256 no longer falls off the cliff, and the expensive member
 compare runs once instead of once per group tile.  The [chunks, G, 7]
 partials fold to [G, 7] with `tree_fold_partials` ON DEVICE (pairwise,
@@ -80,11 +85,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..config import resolve_interpret
+
 _I32_MAX = jnp.iinfo(jnp.int32).max
 _I32_MIN = jnp.iinfo(jnp.int32).min
 
 # pages packed per select row: 64 tag + 64 field + 64 gid + 64 zero lanes
 SELECT_BLOCK = 64
+# rows per select grid step (and the unit of `rows_per_step`): the TPU's
+# sublane tile, so no output block is thinner than 8 rows
+SELECT_ROWS = 8
 
 
 def _resolve_tag_x(mem_ref, scal_ref, ts_ref, data_ref):
@@ -107,39 +117,6 @@ def _resolve_tag_x(mem_ref, scal_ref, ts_ref, data_ref):
     data = data_ref[...]                                   # [BP, K, E]
     sel = jnp.sum(onehot.astype(data.dtype)[:, :, None] * data, axis=1)
     return sel[:, 0], sel[:, 1]                            # tag, x: [BP]
-
-
-def _resolve_block(mem_ref, scal_ref, ts_ref, data_ref):
-    """Resolve + scalar tag test: (x, valid, thresh) for the scalar
-    kernel, tags/threshold from the scal tile."""
-    tag, x = _resolve_tag_x(mem_ref, scal_ref, ts_ref, data_ref)
-    tag_main = scal_ref[0, 1]
-    tag_alt = scal_ref[0, 2]
-    thresh = scal_ref[0, 3]
-    valid = (tag == tag_main) | (tag == tag_alt)
-    return x, valid, thresh
-
-
-def _kernel(mem_ref, scal_ref, ts_ref, data_ref, out_ref):
-    # --- fused aggregate over the visible payloads ----------------------
-    x, valid, thresh = _resolve_block(mem_ref, scal_ref, ts_ref, data_ref)
-    below = valid & (x < thresh)
-    psum = jnp.sum(jnp.where(valid, x, 0))
-    pcount = jnp.sum(valid.astype(jnp.int32))
-    pbelow = jnp.sum(below.astype(jnp.int32))
-    pmin = jnp.min(jnp.where(valid, x, _I32_MAX))
-    pmax = jnp.max(jnp.where(valid, x, _I32_MIN))
-    pabove = jnp.sum((valid & (x > thresh)).astype(jnp.int32))
-    psumb = jnp.sum(jnp.where(below, x, 0))
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
-    tile = jnp.where(lane == 0, psum, 0)
-    tile = jnp.where(lane == 1, pcount, tile)
-    tile = jnp.where(lane == 2, pbelow, tile)
-    tile = jnp.where(lane == 3, pmin, tile)
-    tile = jnp.where(lane == 4, pmax, tile)
-    tile = jnp.where(lane == 5, pabove, tile)
-    tile = jnp.where(lane == 6, psumb, tile)
-    out_ref[...] = tile                        # this block's partial row
 
 
 def _scal_tile(floor, tag_main, tag_alt, threshold):
@@ -192,33 +169,18 @@ def rss_scan_agg(data: jax.Array, ts: jax.Array, member_ts: jax.Array,
                  tag_alt: jax.Array | int = -2,
                  threshold: jax.Array | int = _I32_MAX,
                  *, block_pages: int = 8,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """Fused RSS membership scan + aggregate; returns [P/BP, 7] int32
     per-block partials of [sum, count, count_below, min, max,
     count_above, sum_below] over member-visible payloads whose tag is
     tag_main or tag_alt (fold the block axis on host — lanes 0-2 and 5-6
-    add, 3 min, 4 max).  interpret=True executes on CPU (validation);
-    interpret=False targets TPU."""
-    P, K, E = data.shape
-    assert ts.shape == (P, K)
-    bp = min(block_pages, P)
-    assert P % bp == 0, (P, bp)
-    mem, mp = _mem_tile(member_ts)
-    scal = _scal_tile(floor, tag_main, tag_alt, threshold)
-    out = pl.pallas_call(
-        _kernel,
-        grid=(P // bp,),
-        in_specs=[
-            pl.BlockSpec((1, mp), lambda i: (0, 0)),        # members
-            pl.BlockSpec((1, 128), lambda i: (0, 0)),       # scalar params
-            pl.BlockSpec((bp, K), lambda i: (i, 0)),        # ts
-            pl.BlockSpec((bp, K, E), lambda i: (i, 0, 0)),  # data
-        ],
-        out_specs=pl.BlockSpec((1, 128), lambda i: (i, 0)),  # partial rows
-        out_shape=jax.ShapeDtypeStruct((P // bp, 128), jnp.int32),
-        interpret=interpret,
-    )(mem, scal, ts, data)
-    return out[:, :7]
+    add, 3 min, 4 max).  Lowered onto the flat grouped kernel with one
+    group, so the scalar and grouped aggregates share one kernel."""
+    gid = jnp.zeros((data.shape[0], 1), jnp.int32)
+    return rss_scan_agg_grouped(data, ts, gid, member_ts, floor, tag_main,
+                                tag_alt, threshold, n_groups=1,
+                                block_pages=block_pages,
+                                interpret=interpret)[:, 0]
 
 
 def _grouped_kernel(mem_ref, scal_ref, gprm_ref, gid_ref, ts_ref, data_ref,
@@ -265,7 +227,7 @@ def rss_scan_agg_grouped(data: jax.Array, ts: jax.Array, gid: jax.Array,
                          threshold: jax.Array | int = _I32_MAX,
                          *, n_groups: int = 1, block_pages: int = 8,
                          group_params: jax.Array | None = None,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """Fused RSS membership scan + GROUPED aggregate (flat-lane): `gid` is
     a [P, 1] int32 group id per page (0..n_groups-1; -1 = no group,
     matching no accumulator lane — sublane padding).  Returns [P/BP,
@@ -280,7 +242,7 @@ def rss_scan_agg_grouped(data: jax.Array, ts: jax.Array, gid: jax.Array,
     assert ts.shape == (P, K) and gid.shape == (P, 1)
     assert n_groups >= 1
     bp = min(block_pages, P)
-    assert P % bp == 0, (P, bp)
+    assert bp % 8 == 0 and P % bp == 0, (P, bp)
     gp = -(-n_groups // 8) * 8                 # sublane-aligned group rows
     mem, mp = _mem_tile(member_ts)
     scal = _scal_tile(floor, tag_main, tag_alt, threshold)
@@ -301,7 +263,7 @@ def rss_scan_agg_grouped(data: jax.Array, ts: jax.Array, gid: jax.Array,
         # along rows: block i owns rows [i*Gp, (i+1)*Gp)
         out_specs=pl.BlockSpec((gp, 128), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((P // bp * gp, 128), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(mem, scal, gtile, gid.astype(jnp.int32), ts, data)
     return out.reshape(P // bp, gp, 128)[:, :n_groups, :7]
 
@@ -311,62 +273,65 @@ def rss_scan_agg_grouped(data: jax.Array, ts: jax.Array, gid: jax.Array,
 # ---------------------------------------------------------------------------
 
 def _select_kernel(mem_ref, scal_ref, gid_ref, ts_ref, data_ref, out_ref):
-    """Stage one: resolve visibility for SELECT_BLOCK pages and pack
-    (tag, field, gid) into one [1, 4*SELECT_BLOCK] row — the expensive
-    member compare runs exactly once per page, independent of G."""
+    """Stage one: resolve visibility for SELECT_ROWS * SELECT_BLOCK pages
+    and pack (tag, field, gid) into SELECT_ROWS rows of 4*SELECT_BLOCK
+    lanes — the expensive member compare runs exactly once per page,
+    independent of G."""
     tag, x = _resolve_tag_x(mem_ref, scal_ref, ts_ref, data_ref)
-    gid = gid_ref[...][:, 0]                               # [SB]
-    row = jnp.concatenate([tag, x, gid, jnp.zeros_like(tag)])
-    out_ref[...] = row[None, :]
+    gid = gid_ref[...][:, 0]                               # [R*SB]
+    rows = out_ref.shape[0]
+    out_ref[...] = jnp.concatenate(
+        [v.reshape(rows, SELECT_BLOCK)
+         for v in (tag, x, gid, jnp.zeros_like(tag))], axis=1)
 
 
 def _chunk_reduce_kernel(gprm_ref, sel_ref, out_ref):
     """Stage two: re-reduce the packed select stream over a TILED group
     axis.  Grid (G/GT, chunks, steps); each step folds `rows_per_step`
     select rows into its (chunk, group-tile) partial via @pl.when
-    revisits, so live VMEM is one [GT, 128] tile — bounded by the group
-    tile, not by G."""
+    revisits, so live VMEM is one group tile — bounded by the group tile,
+    not by G.  The select rows stay [R, SB] (the TPU cannot cast lanes
+    into sublanes), so groups ride the leading axis: params [GT, 1, 128],
+    masks [GT, R, SB], partials [GT, 1, 128]."""
     i = pl.program_id(2)                                   # step in chunk
     j = pl.program_id(0)                                   # group tile
     sb = SELECT_BLOCK
     blk = sel_ref[...]                                     # [R, 4*SB]
-    tag = blk[:, 0:sb].reshape(-1)                         # [R*SB]
-    x = blk[:, sb:2 * sb].reshape(-1)
-    gid = blk[:, 2 * sb:3 * sb].reshape(-1)
-    prm = gprm_ref[...]                                    # [GT, 128]
+    tag = blk[:, 0:sb][None]                               # [1, R, SB]
+    x = blk[:, sb:2 * sb][None]
+    gid = blk[:, 2 * sb:3 * sb][None]
+    prm = gprm_ref[...]                                    # [GT, 1, 128]
     gt = prm.shape[0]
     # global group ids covered by this tile
-    gl = j * gt + jax.lax.broadcasted_iota(jnp.int32, (1, gt), 1)[0]
-    tagm = ((tag[:, None] == prm[:, 0][None, :]) |
-            (tag[:, None] == prm[:, 1][None, :]))
-    grp = (gid[:, None] == gl[None, :]) & tagm             # [R*SB, GT]
-    thresh = prm[:, 2][None, :]
-    xg = x[:, None]
-    below = grp & (xg < thresh)
-    psum = jnp.sum(jnp.where(grp, xg, 0), axis=0)          # [GT]
-    pcount = jnp.sum(grp.astype(jnp.int32), axis=0)
-    pbelow = jnp.sum(below.astype(jnp.int32), axis=0)
-    pmin = jnp.min(jnp.where(grp, xg, _I32_MAX), axis=0)
-    pmax = jnp.max(jnp.where(grp, xg, _I32_MIN), axis=0)
-    pabove = jnp.sum((grp & (xg > thresh)).astype(jnp.int32), axis=0)
-    psumb = jnp.sum(jnp.where(below, xg, 0), axis=0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, gt, 128), 2)
-    tile = jnp.where(lane == 0, psum[None, :, None], 0)
-    tile = jnp.where(lane == 1, pcount[None, :, None], tile)
-    tile = jnp.where(lane == 2, pbelow[None, :, None], tile)
-    tile = jnp.where(lane == 3, pmin[None, :, None], tile)
-    tile = jnp.where(lane == 4, pmax[None, :, None], tile)
-    tile = jnp.where(lane == 5, pabove[None, :, None], tile)
-    tile = jnp.where(lane == 6, psumb[None, :, None], tile)
+    gl = j * gt + jax.lax.broadcasted_iota(jnp.int32, (gt, 1, 1), 0)
+    grp = (gid == gl) & ((tag == prm[:, :, 0:1]) |
+                         (tag == prm[:, :, 1:2]))          # [GT, R, SB]
+    thresh = prm[:, :, 2:3]
+    below = grp & (x < thresh)
+
+    def total(v, red=jnp.sum):                             # -> [GT, 1, 1]
+        return red(red(v, axis=2, keepdims=True), axis=1, keepdims=True)
+
+    stats = (total(jnp.where(grp, x, 0)),
+             total(grp.astype(jnp.int32)),
+             total(below.astype(jnp.int32)),
+             total(jnp.where(grp, x, _I32_MAX), jnp.min),
+             total(jnp.where(grp, x, _I32_MIN), jnp.max),
+             total((grp & (x > thresh)).astype(jnp.int32)),
+             total(jnp.where(below, x, 0)))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (gt, 1, 128), 2)
+    tile = jnp.zeros((gt, 1, 128), jnp.int32)
+    for k, v in enumerate(stats):
+        tile = jnp.where(lane == k, v, tile)
 
     @pl.when(i == 0)
     def _init():
-        out_ref[...] = tile
+        out_ref[0] = tile
 
     @pl.when(i > 0)
     def _accumulate():
-        prev = out_ref[...]
-        out_ref[...] = jnp.where(
+        prev = out_ref[0]
+        out_ref[0] = jnp.where(
             (lane < 3) | (lane >= 5), prev + tile,
             jnp.where(lane == 3, jnp.minimum(prev, tile),
                       jnp.maximum(prev, tile)))
@@ -375,10 +340,13 @@ def _chunk_reduce_kernel(gprm_ref, sel_ref, out_ref):
 def _chunk_shape(P: int, rows_per_step: int, fold_chunks: int):
     """Static chunking math shared by kernel and ref: pad P to
     rows * SELECT_BLOCK pages where rows divides evenly into
-    `fold_chunks`-or-fewer chunks of `rows_per_step`-row steps."""
+    `fold_chunks`-or-fewer chunks of `rows_per_step`-row steps
+    (`rows_per_step` a multiple of SELECT_ROWS)."""
+    assert rows_per_step >= SELECT_ROWS and \
+        rows_per_step % SELECT_ROWS == 0, rows_per_step
     sb = SELECT_BLOCK
     rows0 = max(1, -(-P // sb))
-    r = max(1, min(rows_per_step, rows0))
+    r = rows_per_step
     nc = max(1, min(fold_chunks, rows0 // r))
     unit = r * nc
     rows = -(-rows0 // unit) * unit
@@ -413,7 +381,7 @@ def rss_scan_agg_chunked(data: jax.Array, ts: jax.Array, gid: jax.Array,
                          group_tile: int = 8,
                          rows_per_step: int = 8,
                          fold_chunks: int = 8,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """Chunked two-stage grouped scan+agg: one select pass packs
     (tag, field, gid) per page, then a tiled-group reduce re-reads the
     packed stream — VMEM bounded by `group_tile`, visibility resolved
@@ -435,17 +403,19 @@ def rss_scan_agg_chunked(data: jax.Array, ts: jax.Array, gid: jax.Array,
     scal = _scal_tile(floor, tag_main, tag_alt, threshold)
     gtile = _group_param_tile(n_groups, gp, tag_main, tag_alt, threshold,
                               group_params)
+    sr = SELECT_ROWS
+    interpret = resolve_interpret(interpret)
     sel = pl.pallas_call(
         _select_kernel,
-        grid=(rows,),
+        grid=(rows // sr,),
         in_specs=[
             pl.BlockSpec((1, mp), lambda i: (0, 0)),        # members
             pl.BlockSpec((1, 128), lambda i: (0, 0)),       # scalar params
-            pl.BlockSpec((sb, 1), lambda i: (i, 0)),        # group ids
-            pl.BlockSpec((sb, K), lambda i: (i, 0)),        # ts
-            pl.BlockSpec((sb, K, E), lambda i: (i, 0, 0)),  # data
+            pl.BlockSpec((sr * sb, 1), lambda i: (i, 0)),   # group ids
+            pl.BlockSpec((sr * sb, K), lambda i: (i, 0)),   # ts
+            pl.BlockSpec((sr * sb, K, E), lambda i: (i, 0, 0)),  # data
         ],
-        out_specs=pl.BlockSpec((1, 4 * sb), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((sr, 4 * sb), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, 4 * sb), jnp.int32),
         interpret=interpret,
     )(mem, scal, gid, ts, data)
@@ -455,15 +425,15 @@ def rss_scan_agg_chunked(data: jax.Array, ts: jax.Array, gid: jax.Array,
         _chunk_reduce_kernel,
         grid=(ngt, nc, bpc),
         in_specs=[
-            pl.BlockSpec((group_tile, 128), lambda j, c, i: (j, 0)),
+            pl.BlockSpec((group_tile, 1, 128), lambda j, c, i: (j, 0, 0)),
             pl.BlockSpec((r, 4 * sb), lambda j, c, i: (c * bpc + i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, group_tile, 128),
-                               lambda j, c, i: (c, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((nc, gp, 128), jnp.int32),
+        out_specs=pl.BlockSpec((1, group_tile, 1, 128),
+                               lambda j, c, i: (c, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nc, gp, 1, 128), jnp.int32),
         interpret=interpret,
-    )(gtile, sel)
-    return out[:, :n_groups, :7]
+    )(gtile[:, None, :], sel)
+    return out[:, :n_groups, 0, :7]
 
 
 # ---------------------------------------------------------------------------
@@ -522,7 +492,7 @@ def _delta_fold_kernel(acc_ref, delta_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def rss_delta_fold(acc: jax.Array, delta: jax.Array, *,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """Advance a materialized-aggregate accumulator tile by a dense delta
     buffer: acc [Lp, 128] int32 (lane rows, sublane-aligned), delta
     [Dp, 128] int32 change rows (see `_delta_fold_kernel` for the column
@@ -530,7 +500,11 @@ def rss_delta_fold(acc: jax.Array, delta: jax.Array, *,
     the advanced [Lp, 128] tile — O(delta) work, independent of table
     size.  int32 throughout: callers bound |contribution| and the pending
     buffer length so neither a row delta nor an additive accumulator lane
-    can wrap (the `tensorstore.materialized` overflow ladder)."""
+    can wrap (the `tensorstore.materialized` overflow ladder).  The whole
+    buffer is one block, and the TPU compile time grows steeply with Dp
+    (for a v5e: about a second at 1,024 rows, unfinished after 15
+    minutes at 4,096), so callers keep Dp small
+    (`materialized.FLUSH_ROWS`)."""
     lp, dp = acc.shape[0], delta.shape[0]
     assert acc.shape == (lp, 128) and delta.shape == (dp, 128)
     assert lp % 8 == 0 and dp % 8 == 0, (lp, dp)
@@ -543,7 +517,7 @@ def rss_delta_fold(acc: jax.Array, delta: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((lp, 128), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((lp, 128), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(acc, delta)
 
 

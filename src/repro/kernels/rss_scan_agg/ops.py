@@ -11,14 +11,19 @@ fused_recurrent idiom): small scans go "host" (launch overhead dominates
 call, or globally via the REPRO_GROUPED_MODE env var.
 
 Overflow guard: device partials are int32.  The flat path only needs one
-BP-page block's partial to fit (|field| max * BP < 2**31) — when the
-store's field magnitude violates that, the block size is SHRUNK until it
-fits (BP=1 always does: a single int32 value cannot overflow), keeping
-the host Python-int fold exact.  The chunked path folds ON DEVICE, so it
-needs the whole-scan bound (|field| max * P < 2**31) and falls back to
-flat-lane when violated.  `LAUNCH_STATS` counts dispatches, pallas
-calls, chosen modes, shrinks and fallbacks — the driver and verify.sh
-read it to assert one-launch-per-fused-batch."""
+BP-page block's partial to fit (|field| max * BP < 2**31).  BP is never
+below 8 (the TPU's sublane tile), so when the store's field magnitude
+violates the bound at BP=8 the op computes per-page partials with the
+jnp reference instead (a single int32 value cannot overflow) and folds
+them exactly on host.  The chunked path folds ON DEVICE, so it needs the
+whole-scan bound (|field| max * P < 2**31) and falls back to flat-lane
+when violated.  Both fallbacks count `overflow_fallbacks`.
+`LAUNCH_STATS` counts dispatches, pallas calls, chosen modes and
+fallbacks — the driver and verify.sh read it to assert
+one-launch-per-fused-batch.
+
+`interpret` passes through to the kernels, which resolve None from the
+backend (`repro.kernels.config`)."""
 
 from __future__ import annotations
 
@@ -30,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...obs import REGISTRY, StatsView
-from ..config import resolve_interpret
 from .kernel import (rss_delta_fold, rss_scan_agg, rss_scan_agg_chunked,
                      rss_scan_agg_grouped, tree_fold_partials)
 from .ref import (rss_delta_fold_ref, rss_scan_agg_chunked_ref,
@@ -49,7 +53,7 @@ _chunked_ref = jax.jit(rss_scan_agg_chunked_ref,
 _I32_MAX = jnp.iinfo(jnp.int32).max
 _I32_MIN = jnp.iinfo(jnp.int32).min
 
-BLOCK_PAGES = 8                   # default flat/scalar grid block
+BLOCK_PAGES = 8                   # flat/scalar grid block: one sublane tile
 
 # --- shape dispatch ---------------------------------------------------------
 
@@ -68,8 +72,7 @@ FLAT_MODE_MAX_GROUPS = 32
 # layer's metrics; dict-shaped API preserved for existing readers
 LAUNCH_STATS = StatsView(REGISTRY, "kernel_launch",
                          ("dispatches", "pallas_calls", "host", "flat",
-                          "chunked", "block_shrinks", "overflow_fallbacks",
-                          "delta_folds"))
+                          "chunked", "overflow_fallbacks", "delta_folds"))
 
 
 def reset_launch_stats() -> dict:
@@ -105,26 +108,11 @@ def field_maxabs(store: dict) -> int:
     return int(np.abs(col.astype(np.int64)).max()) if col.size else 0
 
 
-def safe_block_pages(maxabs: int, n_pages: int,
-                     preferred: int = BLOCK_PAGES) -> int:
-    """Largest block size <= preferred whose per-block partial provably
-    fits int32 (maxabs * BP < 2**31).  Halving keeps P % BP == 0 (stores
-    are sublane-padded to multiples of 8); BP=1 always fits — a single
-    int32 value cannot overflow its own sum."""
-    bp = max(1, min(preferred, n_pages))
-    while bp > 1 and maxabs > (2**31 - 1) // bp:
-        bp //= 2
-    return bp
-
-
-def check_block_bound(maxabs: int, block_pages: int) -> None:
-    """Raise OverflowError when a BP-page block partial could wrap int32
-    — the guard for callers that pin an explicit block size."""
-    if block_pages > 1 and maxabs > (2**31 - 1) // block_pages:
-        raise OverflowError(
-            f"int32 partial overflow: |field| max {maxabs} * "
-            f"block_pages {block_pages} exceeds 2**31-1; shrink the "
-            f"block (safe_block_pages) or aggregate on host")
+def safe_block_pages(maxabs: int) -> Optional[int]:
+    """BLOCK_PAGES when an 8-page block partial provably fits int32
+    (maxabs * 8 < 2**31), else None: no thinner block may reach the TPU
+    compiler, so the caller takes its exact per-page fallback."""
+    return BLOCK_PAGES if maxabs <= (2**31 - 1) // BLOCK_PAGES else None
 
 
 def scan_bound_ok(maxabs: int, n_pages: int) -> bool:
@@ -167,25 +155,23 @@ def snapshot_agg_members(store: dict, member_ts, floor=0, *,
     sum_below] as Python ints
     (per-block int32 partials on device, exact fold on host);
     `tensorstore.version_store.finalize_agg` picks the requested statistic
-    (min/max carry sentinels when count == 0).  The block size shrinks
-    automatically when the store's field magnitude could wrap a block
-    partial.  interpret defaults to the REPRO_INTERPRET switch
-    (`repro.kernels.config`)."""
+    (min/max carry sentinels when count == 0).  When the store's field
+    magnitude could wrap an 8-page block partial, the jnp reference
+    computes per-page partials instead (counted in
+    `overflow_fallbacks`)."""
     thresh = _I32_MAX if threshold is None else int(threshold)
-    P = int(store["ts"].shape[0])
-    bp = safe_block_pages(field_maxabs(store), P)
-    if bp != min(BLOCK_PAGES, P):
-        LAUNCH_STATS["block_shrinks"] += 1
-    if not use_kernel:
+    bp = safe_block_pages(field_maxabs(store))
+    if bp is None:
+        LAUNCH_STATS["overflow_fallbacks"] += 1
+    if bp is None or not use_kernel:
         partials = _scan_agg_ref(store["data"], store["ts"], member_ts,
                                  floor, tag_main, tag_alt, thresh,
-                                 block_pages=bp)
+                                 block_pages=bp or 1)
     else:
         LAUNCH_STATS["pallas_calls"] += 1
         partials = rss_scan_agg(store["data"], store["ts"], member_ts,
                                 floor, tag_main, tag_alt, thresh,
-                                block_pages=bp,
-                                interpret=resolve_interpret(interpret))
+                                block_pages=bp, interpret=interpret)
     return fold_partials(partials)
 
 
@@ -229,27 +215,26 @@ def snapshot_group_agg_members(store: dict, gid, n_groups: int,
     Python ints; a group no visible page maps to is [0, 0, 0, INT32_MAX,
     INT32_MIN, 0, 0] (count disambiguates — `finalize_agg` folds the
     sentinels
-    to 0).  Block size shrinks automatically under the overflow bound."""
+    to 0).  Same overflow fallback as `snapshot_agg_members`."""
     thresh = _I32_MAX if threshold is None else int(threshold)
     gid = jnp.asarray(np.asarray(gid, np.int32).reshape(-1, 1))
-    P = int(store["ts"].shape[0])
-    bp = safe_block_pages(field_maxabs(store), P)
-    if bp != min(BLOCK_PAGES, P):
-        LAUNCH_STATS["block_shrinks"] += 1
+    bp = safe_block_pages(field_maxabs(store))
+    if bp is None:
+        LAUNCH_STATS["overflow_fallbacks"] += 1
     if group_params is not None:
         group_params = jnp.asarray(np.asarray(group_params, np.int32))
-    if not use_kernel:
+    if bp is None or not use_kernel:
         partials = _grouped_ref(
             store["data"], store["ts"], gid, member_ts, floor,
             tag_main, tag_alt, thresh, n_groups=n_groups,
-            group_params=group_params, block_pages=bp)
+            group_params=group_params, block_pages=bp or 1)
     else:
         LAUNCH_STATS["pallas_calls"] += 1
         partials = rss_scan_agg_grouped(
             store["data"], store["ts"], gid, member_ts, floor,
             tag_main, tag_alt, thresh, n_groups=n_groups,
             block_pages=bp, group_params=group_params,
-            interpret=resolve_interpret(interpret))
+            interpret=interpret)
     return fold_group_partials(partials)
 
 
@@ -282,7 +267,7 @@ def snapshot_group_agg_chunked(store: dict, gid, n_groups: int,
             store["data"], store["ts"], gid, member_ts, floor,
             tag_main, tag_alt, thresh, n_groups=n_groups,
             group_params=group_params, group_tile=group_tile,
-            interpret=resolve_interpret(interpret))
+            interpret=interpret)
     return np.asarray(tree_fold_partials(partials)).tolist()
 
 
@@ -309,8 +294,7 @@ def delta_fold(acc, delta, *, use_kernel: bool = True,
     if not use_kernel:
         return _delta_fold_ref_j(acc, delta)
     LAUNCH_STATS["pallas_calls"] += 1
-    return rss_delta_fold(acc, delta,
-                          interpret=resolve_interpret(interpret))
+    return rss_delta_fold(acc, delta, interpret=interpret)
 
 
 def grouped_agg_auto(store: dict, gid, n_groups: int, member_ts, floor=0,

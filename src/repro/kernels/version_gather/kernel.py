@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..config import resolve_interpret
+
 
 def _kernel(wm_ref, ts_ref, data_ref, out_ref):
     ts = ts_ref[...]                         # [BP, K] int32
@@ -47,9 +49,9 @@ def _kernel(wm_ref, ts_ref, data_ref, out_ref):
                                              "interpret"))
 def version_gather(data: jax.Array, ts: jax.Array, watermark: jax.Array,
                    *, block_pages: int = 8, block_elems: int = 512,
-                   interpret: bool = True) -> jax.Array:
-    """Pallas snapshot read.  interpret=True executes on CPU (validation);
-    interpret=False targets TPU."""
+                   interpret: bool | None = None) -> jax.Array:
+    """Pallas snapshot read.  interpret=None resolves from the backend
+    (`repro.kernels.config`): compiled on TPU, interpreted elsewhere."""
     P, K, E = data.shape
     assert ts.shape == (P, K)
     bp = min(block_pages, P)
@@ -67,5 +69,5 @@ def version_gather(data: jax.Array, ts: jax.Array, watermark: jax.Array,
         ],
         out_specs=pl.BlockSpec((bp, be), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((P, E), data.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(wm, ts, data)

@@ -6,7 +6,6 @@ from typing import Optional
 
 import jax
 
-from ..config import resolve_interpret
 from .kernel import version_gather
 from .ref import version_gather_ref
 
@@ -15,10 +14,9 @@ def snapshot_read(store: dict, watermark, *, use_kernel: bool = True,
                   interpret: Optional[bool] = None) -> jax.Array:
     """SI-V read over a paged store {'data': [P,K,E], 'ts': [P,K]}.
 
-    interpret defaults to the REPRO_INTERPRET switch
-    (`repro.kernels.config`): interpret mode validates the kernel code path
-    on CPU; REPRO_INTERPRET=0 (or interpret=False) compiles for TPU."""
+    interpret=None resolves from the backend (`repro.kernels.config`):
+    compiled on TPU, interpret mode elsewhere."""
     if not use_kernel:
         return version_gather_ref(store["data"], store["ts"], watermark)
     return version_gather(store["data"], store["ts"], watermark,
-                          interpret=resolve_interpret(interpret))
+                          interpret=interpret)

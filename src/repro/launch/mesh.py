@@ -13,18 +13,21 @@ device state (the dry-run must set XLA_FLAGS before any device query).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh over the locally available devices (CPU smoke tests)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple:

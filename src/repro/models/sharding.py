@@ -31,10 +31,7 @@ def with_mesh(mesh: Mesh, axis_map: Optional[dict] = None):
     tok1 = _MESH.set(mesh)
     tok2 = _AXIS_MAP.set(amap)
     try:
-        # jax >= 0.6 spells mesh activation jax.set_mesh; older releases use
-        # the Mesh object itself as the context manager.
-        ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-        with ctx:
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _MESH.reset(tok1)

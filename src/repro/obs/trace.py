@@ -8,9 +8,8 @@ time?" per replica / policy / plan kind / kernel mode.
 
 Capture is OFF by default and costs one cached boolean check per
 `span()` call (a shared no-op context manager is returned, nothing
-allocated).  Enable with ``REPRO_TRACE=1`` — resolved once at import,
-mirroring ``REPRO_INTERPRET`` in `repro.kernels.config` — or at runtime
-via `TRACER.set_enabled(True)`.  Even when enabled, spans are plain
+allocated).  Enable with ``REPRO_TRACE=1`` — resolved once at import —
+or at runtime via `TRACER.set_enabled(True)`.  Even when enabled, spans are plain
 perf_counter pairs and small dicts: no I/O, no thread handoff.
 
 The tracer also keeps always-on `spans_opened` / `spans_closed`

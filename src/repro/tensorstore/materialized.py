@@ -230,10 +230,13 @@ class MaterializedView:
                 if nv:
                     sh[3] = min(sh[3], newv)
                     sh[4] = max(sh[4], newv)
+                if len(self.pending) >= FLUSH_ROWS:
+                    # flush per row, not per record: a bulk-load record
+                    # must not build a delta buffer past FLUSH_ROWS (the
+                    # overflow bound above, and the fold kernel's size)
+                    self._flush()
         self.seed_seq = seq
         self.last_lsn = rec.lsn
-        if len(self.pending) >= FLUSH_ROWS:
-            self._flush()
 
     def _flush(self) -> None:
         """Fold the pending delta rows into the device tile — ONE

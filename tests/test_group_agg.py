@@ -217,7 +217,8 @@ def test_chunked_kernel_matches_ref_and_flat(seed):
     from repro.kernels.rss_scan_agg.ref import rss_scan_agg_chunked_ref
 
     rng = np.random.default_rng(seed)
-    for P, K, E in [(8, 3, 8), (72, 4, 16), (256, 4, 8)]:
+    # 1096 pages: 18 select rows -> 2 chunks of 2 eight-row steps each
+    for P, K, E in [(8, 3, 8), (72, 4, 16), (1096, 4, 8)]:
         data = np.zeros((P, K, E), np.int32)
         data[:, :, 0] = rng.integers(-1, 4, (P, K))     # tags incl. TAG_PAD
         data[:, :, 1] = rng.integers(-100, 100, (P, K))
@@ -236,10 +237,10 @@ def test_chunked_kernel_matches_ref_and_flat(seed):
                     args = (jnp.asarray(data), jnp.asarray(ts),
                             jnp.asarray(gid), jnp.asarray(mem), 23)
                     chunks = rss_scan_agg_chunked(
-                        *args, n_groups=G, rows_per_step=2, fold_chunks=2,
+                        *args, n_groups=G, rows_per_step=8, fold_chunks=2,
                         **params)
                     ref = rss_scan_agg_chunked_ref(
-                        *args, n_groups=G, rows_per_step=2, fold_chunks=2,
+                        *args, n_groups=G, rows_per_step=8, fold_chunks=2,
                         **params)
                     np.testing.assert_array_equal(
                         np.asarray(chunks), np.asarray(ref),

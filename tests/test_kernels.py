@@ -199,46 +199,89 @@ class TestSsmScan:
         np.testing.assert_allclose(h_k, h_model, rtol=2e-4, atol=2e-4)
 
 
-class TestInterpretSwitch:
-    """REPRO_INTERPRET is the ONE switch between interpret-mode validation
-    and TPU-compiled execution for every kernel op (`repro.kernels.config`)."""
+class TestInterpretResolution:
+    """`interpret=None` resolves from the backend at call time
+    (`repro.kernels.config`): compiled on TPU, interpret mode elsewhere;
+    an explicit argument always wins."""
 
-    def test_default_is_interpret(self, monkeypatch):
-        from repro.kernels.config import default_interpret, resolve_interpret
-        monkeypatch.delenv("REPRO_INTERPRET", raising=False)
-        assert default_interpret() is True
-        assert resolve_interpret(None) is True
-
-    @pytest.mark.parametrize("val,want", [
-        ("1", True), ("true", True), ("yes", True), ("", True),
-        ("0", False), ("false", False), ("No", False), ("OFF", False),
-    ])
-    def test_env_values(self, monkeypatch, val, want):
+    @pytest.mark.parametrize("backend,want", [
+        ("cpu", True), ("gpu", True), ("tpu", False)])
+    def test_default_follows_backend(self, monkeypatch, backend, want):
         from repro.kernels.config import default_interpret
-        monkeypatch.setenv("REPRO_INTERPRET", val)
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
         assert default_interpret() is want
 
-    def test_explicit_argument_wins_over_env(self, monkeypatch):
+    @pytest.mark.parametrize("backend,want", [
+        ("cpu", True), ("gpu", True), ("tpu", False)])
+    def test_none_resolves_from_backend(self, monkeypatch, backend, want):
         from repro.kernels.config import resolve_interpret
-        monkeypatch.setenv("REPRO_INTERPRET", "0")
-        assert resolve_interpret(True) is True
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert resolve_interpret(None) is want
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    @pytest.mark.parametrize("explicit", [True, False])
+    def test_explicit_argument_wins(self, monkeypatch, backend, explicit):
+        from repro.kernels.config import resolve_interpret
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert resolve_interpret(explicit) is explicit
+
+    def test_no_environment_switch(self, monkeypatch):
+        """No environment variable can steer the mode: on a TPU backend a
+        leftover REPRO_INTERPRET=1 still resolves to compiled."""
+        from repro.kernels.config import resolve_interpret
+        monkeypatch.setenv("REPRO_INTERPRET", "1")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert resolve_interpret(None) is False
 
-    def test_ops_run_through_the_switch(self, monkeypatch):
-        """An op called with interpret=None resolves through the env switch
-        and still matches its oracle (interpret mode on this CPU)."""
-        from repro.kernels.rss_scan_agg.ops import (fold_partials,
-                                                    snapshot_agg_members)
-        from repro.kernels.rss_scan_agg.ref import rss_scan_agg_ref
-        monkeypatch.setenv("REPRO_INTERPRET", "1")
+    def test_resolved_at_call_time(self, monkeypatch):
+        """The backend is read per call, never cached at import."""
+        from repro.kernels.config import resolve_interpret
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert resolve_interpret(None) is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        assert resolve_interpret(None) is True
+
+    @pytest.mark.parametrize("op", ["scalar", "flat", "chunked", "delta"])
+    def test_ops_default_matches_oracle(self, op):
+        """Each serve-path op called with interpret=None (interpret mode
+        on this CPU backend) matches its jnp oracle."""
+        from repro.kernels.rss_scan_agg import ops as kops
+        from repro.kernels.rss_scan_agg.ref import (
+            rss_delta_fold_ref, rss_scan_agg_chunked_ref,
+            rss_scan_agg_grouped_ref, rss_scan_agg_ref)
         rng = np.random.default_rng(0)
-        data = np.zeros((8, 2, 8), np.int32)
+        P, G = 16, 3
+        data = np.zeros((P, 2, 8), np.int32)
         data[:, :, 0] = 1
-        data[:, :, 1] = rng.integers(0, 50, (8, 2))
-        ts = rng.integers(0, 9, (8, 2)).astype(np.int32)
+        data[:, :, 1] = rng.integers(-50, 50, (P, 2))
+        ts = rng.integers(0, 9, (P, 2)).astype(np.int32)
         store = {"data": jnp.asarray(data), "ts": jnp.asarray(ts)}
-        mem = jnp.asarray([], jnp.int32)
-        out = snapshot_agg_members(store, mem, 5, tag_main=1, tag_alt=0)
-        ref = fold_partials(
-            rss_scan_agg_ref(store["data"], store["ts"], mem, 5, 1, 0))
+        mem = jnp.asarray([6, 8], jnp.int32)
+        gid = rng.integers(-1, G, (P, 1)).astype(np.int32)
+        args = (store["data"], store["ts"])
+        if op == "scalar":
+            out = kops.snapshot_agg_members(store, mem, 5, tag_main=1,
+                                            tag_alt=0, interpret=None)
+            ref = kops.fold_partials(
+                rss_scan_agg_ref(*args, mem, 5, 1, 0))
+        elif op == "flat":
+            out = kops.snapshot_group_agg_members(store, gid, G, mem, 5,
+                                                  interpret=None)
+            ref = kops.fold_group_partials(rss_scan_agg_grouped_ref(
+                *args, jnp.asarray(gid), mem, 5, n_groups=G))
+        elif op == "chunked":
+            out = kops.snapshot_group_agg_chunked(store, gid, G, mem, 5,
+                                                  interpret=None)
+            ref = kops.fold_group_partials(rss_scan_agg_chunked_ref(
+                *args, jnp.asarray(gid), mem, 5, n_groups=G))
+        else:
+            acc = np.zeros((8, 128), np.int32)
+            delta = np.zeros((8, 128), np.int32)
+            delta[:, 0] = rng.integers(-1, 8, 8)
+            delta[:, 1:6] = rng.integers(0, 2, (8, 5))
+            out = np.asarray(kops.delta_fold(acc, delta, interpret=None))
+            ref = np.asarray(rss_delta_fold_ref(jnp.asarray(acc),
+                                                jnp.asarray(delta)))
+            np.testing.assert_array_equal(out, ref)
+            return
         assert out == ref

@@ -21,6 +21,7 @@ def run_sub(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=480)
     assert out.returncode == 0, out.stderr[-4000:]
@@ -30,6 +31,7 @@ def run_sub(code: str, devices: int = 8) -> str:
 def test_smoke_cell_compiles_on_4x2_mesh():
     out = run_sub("""
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs import get_config, smoke_variant
 from repro.models.sharding import with_mesh
 from repro.launch.shardings import param_shardings, batch_shardings
@@ -37,7 +39,8 @@ from repro.train.step import make_train_step, init_state
 from repro.optim import AdamWConfig
 from jax.sharding import NamedSharding
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = smoke_variant(get_config("qwen1.5-0.5b")).with_overrides(fsdp=True)
 opt = AdamWConfig()
 with with_mesh(mesh, {"data": ("data",)}):
@@ -62,6 +65,7 @@ def test_elastic_restore_across_meshes(tmp_path):
     """Save on a 4×2 mesh, restore onto 2×4 — elastic resume."""
     out = run_sub(f"""
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.configs import get_config, smoke_variant
 from repro.models.sharding import with_mesh
 from repro.launch.shardings import param_shardings
@@ -73,12 +77,14 @@ cfg = smoke_variant(get_config("qwen1.5-0.5b")).with_overrides(fsdp=True)
 opt = AdamWConfig()
 state = init_state(jax.random.PRNGKey(0), cfg, opt)
 
-mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+mesh1 = jax.make_mesh((4, 2), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 p1 = jax.device_put(state["params"], param_shardings(mesh1, cfg,
                                                      state["params"]))
 ckpt.save({{"params": p1}}, 1, r"{tmp_path}")
 
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+mesh2 = jax.make_mesh((2, 4), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 template = {{"params": jax.tree.map(
     lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state["params"])}}
 shard2 = {{"params": param_shardings(mesh2, cfg, state["params"])}}

@@ -223,6 +223,29 @@ def test_overflow_degrades_to_clean_fallback():
     assert mirror.exec_stats["view_fallbacks"] > 0
 
 
+def test_bulk_record_folds_in_bounded_buffers(monkeypatch):
+    """A record writing more keys than FLUSH_ROWS (a bulk load) folds in
+    delta buffers of at most FLUSH_ROWS rows: the int32 bound assumes
+    it, and the fold kernel's compile time grows with the buffer."""
+    from repro.kernels.rss_scan_agg import ops as kops
+    from repro.tensorstore.materialized import FLUSH_ROWS
+
+    keys = [f"k:{i:04d}" for i in range(2 * FLUSH_ROWS + 88)]
+    mirror, view, plan = _mirror_with_view({k: 1 for k in keys})
+    rows = []
+    fold = kops.delta_fold
+    monkeypatch.setattr(kops, "delta_fold", lambda acc, delta, **kw: (
+        rows.append(delta.shape[0]) or fold(acc, delta, **kw)))
+    mirror.apply(WalRecord(lsn=2, type="commit", txn=2,
+                           writes=tuple((k, i) for i, k in enumerate(keys)),
+                           seq=2))
+    mirror.advance_views(mirror.watermark)
+    got, _ = mirror.execute_with_writers(plan, mirror.watermark,
+                                         need_writers=False)
+    assert got == (sum(range(len(keys))), 0)
+    assert len(rows) == 3 and max(rows) <= FLUSH_ROWS, rows
+
+
 def test_out_of_order_same_key_fold_degrades():
     """A same-key fold below an already-folded seq would retract the
     newer version — the view must refuse (degrade), never serve it."""

@@ -9,9 +9,9 @@ The tentpole contracts of this PR:
     depending on the strategy the shape dispatcher picks);
   * `select_grouped_mode` routes (P, G, n_plans) shapes between host /
     flat / chunked, overridable per call or via REPRO_GROUPED_MODE;
-  * the int32 overflow guards hold: pinned blocks raise, auto blocks
-    shrink, chunked demotes to flat when the whole-scan bound fails —
-    results stay exact throughout.
+  * the int32 overflow guards hold: past the 8-page block bound the ops
+    take per-page partials, chunked demotes to flat when the whole-scan
+    bound fails — results stay exact throughout.
 """
 
 import random
@@ -267,30 +267,32 @@ class TestSelectGroupedMode:
 
 # ------------------------------------------------------------ overflow guards
 class TestOverflowGuards:
-    def test_check_block_bound_raises(self):
-        kops.check_block_bound(2**27, 8)                 # fits
-        with pytest.raises(OverflowError):
-            kops.check_block_bound(2**28 + 1, 8)
-        kops.check_block_bound(2**31 - 1, 1)             # BP=1 always safe
-
     def test_safe_block_pages_halves(self):
-        assert kops.safe_block_pages(100, 4096) == 8
-        assert kops.safe_block_pages(2**28 + 1, 4096) == 4
-        assert kops.safe_block_pages(2**29, 4096) == 2
-        assert kops.safe_block_pages(2**31 - 1, 4096) == 1
+        """The ladder never goes below one 8-page sublane tile: past the
+        8-page bound it returns None (the exact per-page fallback)."""
+        assert kops.safe_block_pages(100) == 8
+        assert kops.safe_block_pages((2**31 - 1) // 8) == 8
+        assert kops.safe_block_pages(2**28 + 1) is None
+        assert kops.safe_block_pages(2**31 - 1) is None
 
     def test_scan_bound(self):
         assert kops.scan_bound_ok(100, 4096)
         assert not kops.scan_bound_ok(2**28, 16)
         assert kops.scan_bound_ok(0, 0)
 
-    def test_chunked_demotes_to_flat_on_scan_bound(self):
+    @pytest.mark.parametrize("big,fallbacks,pallas", [
+        (2**27 + 7, 1, 1),      # scan bound fails, 8-page block bound holds
+        (2**28 + 7, 2, 0),      # both fail: flat takes per-page partials
+    ])
+    def test_chunked_demotes_to_flat_on_scan_bound(self, big, fallbacks,
+                                                   pallas):
         """Huge field values violate the whole-scan device-fold bound:
         a chunked pick silently demotes to flat (exact host fold) and the
-        result still equals the arbitrary-precision oracle."""
+        result still equals the arbitrary-precision oracle.  Past the
+        8-page block bound the flat op computes per-page partials with
+        the jnp reference instead of a thinner kernel block."""
         eng = Engine("ssi")
         t = eng.begin()
-        big = 2**28 + 7
         for i in range(24):
             eng.write(t, f"k:{i}", big if i % 2 else -big)
         eng.commit(t)
@@ -302,7 +304,25 @@ class TestOverflowGuards:
                            (AggOp("sum", "int"), AggOp("min", "int")))
         kops.reset_launch_stats()
         got = paged.execute(plan, eng.seq)
-        assert kops.LAUNCH_STATS["overflow_fallbacks"] == 1
+        assert kops.LAUNCH_STATS["overflow_fallbacks"] == fallbacks
         assert kops.LAUNCH_STATS["flat"] == 1          # demoted
-        assert kops.LAUNCH_STATS["block_shrinks"] == 1  # BP shrank too
+        assert kops.LAUNCH_STATS["pallas_calls"] == pallas
         assert got == ChainVersionStore(eng.store).execute(plan, eng.seq)
+
+    def test_scalar_agg_past_block_bound_is_exact(self):
+        """A scalar aggregate whose 8-page block partial could wrap int32
+        takes the counted per-page fallback and still equals the oracle."""
+        eng = Engine("ssi")
+        t = eng.begin()
+        for i in range(16):
+            eng.write(t, f"k:{i}", 2**30 + i)
+        eng.commit(t)
+        paged = PagedVersionStore(_mirror_for(eng))
+        plan = AggPlan(tuple(f"k:{i}" for i in range(16)),
+                       AggOp("sum", "int"))
+        kops.reset_launch_stats()
+        got = paged.execute(plan, eng.seq)
+        assert kops.LAUNCH_STATS["overflow_fallbacks"] == 1
+        assert kops.LAUNCH_STATS["pallas_calls"] == 0
+        assert got == ChainVersionStore(eng.store).execute(plan, eng.seq)
+        assert got == 16 * 2**30 + sum(range(16))
