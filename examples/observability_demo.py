@@ -5,8 +5,8 @@ gauges, and fixed-bucket latency histograms; one tracer
 (`repro.obs.TRACER`) captures span trees of the two hot paths:
 
     oltp_commit -> certify -> wal_emit
-    olap_serve  -> route -> [mirror_execute] resolve -> kernel_dispatch
-                   -> finalize
+    olap_serve  -> route -> [mirror_execute] resolve -> dispatch
+                   (serve_upload, serve_kernel > serve_maxabs) -> finalize
 
 The demo runs the single-node HTAP driver with span capture ON, then
 shows what an operator gets for free:

@@ -31,7 +31,7 @@ from __future__ import annotations
 from collections import deque
 from typing import Any, Iterable, Optional, Sequence, Union
 
-from ..obs import (REGISTRY, TRACER, CounterList, StatsView, tick, tock)
+from ..obs import REGISTRY, TRACER, CounterList, StatsView, span
 from ..tensorstore.version_store import Plan
 from .routing import Freshest, RoutingPolicy, make_policy
 from .session import Session
@@ -193,8 +193,7 @@ class ReplicaCluster:
         shipping replays exactly what the replication schedule owed — and
         the token's floor is ratcheted forward after the serve."""
         min_lsn = session.min_required_lsn() if session is not None else 0
-        t0 = tick()
-        with TRACER.span("route", policy=self.policy.name):
+        with span("route", _ROUTE_H, policy=self.policy.name):
             idx = self.policy.choose(self, max_lag=max_lag, min_lsn=min_lsn)
             predicted = self.predicted_lag(idx) if idx is not None else 0
             if idx is None:
@@ -205,11 +204,11 @@ class ReplicaCluster:
                     # staleness was satisfiable — only the session token
                     # wasn't: the freshest replica's delta ship covers it
                     # (cadence-owed records, not an emergency round)
-                    with TRACER.span("token_ship", replica=idx):
+                    with span("token_ship", replica=idx):
                         self.ship(idx, record_cadence=False)
                     self.stats["token_ships"] += 1
                 else:
-                    with TRACER.span("ship_then_serve", replica=idx):
+                    with span("ship_then_serve", replica=idx):
                         self.ship(idx, record_cadence=False)
                     self.stats["ship_then_serve"] += 1
             elif getattr(self.policy, "predictive", False) and \
@@ -223,11 +222,11 @@ class ReplicaCluster:
                 # served as-is: no ship, no extra work.
                 bound = self.policy.effective_bound(max_lag)
                 if self.replicas[idx].applied_lsn < min_lsn:
-                    with TRACER.span("token_ship", replica=idx):
+                    with span("token_ship", replica=idx):
                         self.ship(idx, record_cadence=False)
                     self.stats["token_ships"] += 1
                 elif bound is not None and self.lag_records(idx) > bound:
-                    with TRACER.span("scheduled_ship", replica=idx):
+                    with span("scheduled_ship", replica=idx):
                         self.ship(idx, record_cadence=False)
                     self.stats["scheduled_ships"] += 1
                 else:
@@ -249,7 +248,6 @@ class ReplicaCluster:
                 if rep.applied_lsn < min_lsn:      # must never happen
                     self.stats["token_violations"] += 1
                 session.note_read(rep.applied_lsn, idx)
-        tock(_ROUTE_H, t0)
         return handle
 
     def avg_served_lag(self) -> float:

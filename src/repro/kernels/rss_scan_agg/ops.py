@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...obs import REGISTRY, StatsView
+from ...obs import REGISTRY, StatsView, span
 from .kernel import (rss_delta_fold, rss_scan_agg, rss_scan_agg_chunked,
                      rss_scan_agg_grouped, tree_fold_partials)
 from .ref import (rss_delta_fold_ref, rss_scan_agg_chunked_ref,
@@ -101,11 +101,17 @@ def select_grouped_mode(n_pages: int, n_groups: int, n_plans: int = 1, *,
 
 # --- overflow guard ---------------------------------------------------------
 
+# the overflow guard's device-to-host copy of the whole store plus its
+# host reduction, once per call
+_MAXABS_H = REGISTRY.histogram("serve_maxabs_seconds")
+
+
 def field_maxabs(store: dict) -> int:
     """Largest |aggregable field| (payload element 1) across every slot of
     the store — the host-side input to the int32 partial bounds."""
-    col = np.asarray(store["data"])[:, :, 1]
-    return int(np.abs(col.astype(np.int64)).max()) if col.size else 0
+    with span("serve_maxabs", _MAXABS_H):
+        col = np.asarray(store["data"])[:, :, 1]
+        return int(np.abs(col.astype(np.int64)).max()) if col.size else 0
 
 
 def safe_block_pages(maxabs: int) -> Optional[int]:

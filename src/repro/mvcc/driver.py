@@ -176,11 +176,6 @@ def _harvest_obs(m: Metrics) -> None:
     m.serve_stage_latency = REGISTRY.hist_group("olap_stage_seconds",
                                                 "stage")
     m.oltp_commit_latency = REGISTRY.hist_summary("oltp_commit_seconds")
-    # peaks as gauges, so snapshot()/export surfaces them alongside the
-    # counter families
-    REGISTRY.gauge("driver_peak_engine_txns").track_max(m.max_engine_txns)
-    REGISTRY.gauge("driver_peak_rss_tracked").track_max(m.max_rss_tracked)
-    REGISTRY.gauge("driver_peak_wal_records").track_max(m.max_wal_records)
 
 
 class _PlanBatcher:

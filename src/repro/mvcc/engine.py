@@ -32,10 +32,10 @@ from ..core.history import History, b as op_b, r as op_r, w as op_w, \
     c as op_c, a as op_a
 from ..core.replica import RssSnapshot
 from ..core.wal import Wal, WalRecord
-from ..obs import REGISTRY, TRACER, LabeledCounterMap, StatsView, tick, tock
+from ..obs import REGISTRY, LabeledCounterMap, StatsView, span
 from ..tensorstore.version_store import (ChainVersionStore, Plan,
                                          VersionStore, apply_plan, plan_keys)
-from .store import Store, Version
+from .store import GC_PRUNE_H, Store, Version
 
 
 class Status(Enum):
@@ -280,12 +280,13 @@ class Engine:
     # ----------------------------------------------------------------- commit
     def commit(self, t: Txn) -> None:
         self._check_active(t)
-        t0 = tick()
-        with TRACER.span("oltp_commit", certifier=self.certifier.name,
-                         n_reads=len(t.reads), n_writes=len(t.writes)):
-            tc = tick()
+        # each span observes on a normal exit only: histogram counts ==
+        # engine commits (an abort raises out of certify and commit)
+        with span("oltp_commit", self._commit_hist,
+                  certifier=self.certifier.name, n_reads=len(t.reads),
+                  n_writes=len(t.writes)):
             try:
-                with TRACER.span("certify"):
+                with span("certify", self._certify_hist):
                     if t.writes:
                         # SI-W first-committer-wins: a version committed
                         # after our snapshot on any written key aborts us.
@@ -299,14 +300,12 @@ class Engine:
             except SerializationFailure as e:
                 self._abort(t, e.reason)
                 raise
-            tock(self._certify_hist, tc)
             cseq = self._tick()
             for key, value in t.writes.items():
                 self.store.chain(key).install(cseq, t.tid, value)
             t.status, t.end_seq = Status.COMMITTED, cseq
             self.active.pop(t.tid, None)
-            tw = tick()
-            with TRACER.span("wal_emit"):
+            with span("wal_emit", self._wal_hist):
                 self.wal.log_commit(t.tid, sorted(t.writes.items()),
                                     seq=cseq)
                 if t.out_rw:
@@ -314,15 +313,12 @@ class Engine:
                     # edges of a just-committed reader, for replica-side
                     # RSS construction.
                     self.wal.log_deps(t.tid, sorted(t.out_rw))
-            tock(self._wal_hist, tw)
             if self.history is not None:
                 self.history.append(op_c(t.tid))
             self.stats["commits"] += 1
             if self._tracked(t):
                 self.certifier.on_end(t, committed=True)
             self._gc()
-            # observed on success only: histogram count == engine commits
-            tock(self._commit_hist, t0)
 
     def abort(self, t: Txn) -> None:
         self._abort(t, AbortReason.USER)
@@ -423,7 +419,8 @@ class Engine:
         self.certifier.on_gc(deadset)
 
     def prune_versions(self, floor_seq: int) -> int:
-        n = self.store.prune(floor_seq)
+        with span("gc_prune", GC_PRUNE_H["primary"], node="primary"):
+            n = self.store.prune(floor_seq)
         self.stats["gc_versions"] += n
         return n
 

@@ -42,7 +42,7 @@ from typing import Any, Optional, Sequence
 from ..cluster import ReplicaCluster
 from ..core.replica import PRoTManager, RSSManager, RssSnapshot
 from ..core.wal import effective_commit_seq
-from ..obs import REGISTRY, TRACER, tick, tock
+from ..obs import REGISTRY, span
 from ..tensorstore.mirror import PagedMirror
 from ..tensorstore.version_store import (AggPlan, BatchPlan,
                                          ChainVersionStore, GroupByPlan,
@@ -50,12 +50,19 @@ from ..tensorstore.version_store import (AggPlan, BatchPlan,
                                          Plan, VersionStore, apply_plan,
                                          plan_keys)
 from .engine import AbortReason, Engine, SerializationFailure, Status, Txn
-from .store import Store
+from .store import GC_PRUNE_H, Store
 
 # single-node route stage: PRoT snapshot acquisition (the multi-node twin
 # — policy choice + cadence/ship decision — is timed in cluster.acquire
 # into the SAME series)
 _ROUTE_H = REGISTRY.histogram("olap_stage_seconds", stage="route")
+# the refresh / ship stages, one family each, labelled by node: the RSS
+# manager's WAL replay and construction (Algorithm 1), and the replica's
+# whole WAL-record loop (RSS apply, mirror apply, chain install)
+_RSS_CONSTRUCT_H = {node: REGISTRY.histogram("rss_construct_seconds",
+                                             node=node)
+                    for node in ("primary", "replica")}
+_SHIP_REPLAY_H = REGISTRY.histogram("ship_replay_seconds", node="replica")
 
 
 def _serve_hist(cache: dict, key: tuple, **labels):
@@ -126,8 +133,10 @@ class SingleNodeHTAP:
         bound the bookkeeping: prune RSS per-txn state below the oldest
         pinned PRoT snapshot and recycle the WAL prefix every consumer has
         applied."""
-        self.rss_manager.catch_up(self.engine.wal)
-        snap = self.rss_manager.construct()
+        with span("rss_construct", _RSS_CONSTRUCT_H["primary"],
+                  node="primary"):
+            self.rss_manager.catch_up(self.engine.wal)
+            snap = self.rss_manager.construct()
         if self.mirror is not None:
             self.mirror.catch_up(self.engine.wal,
                                  gc_floor=self.prot.gc_floor_seq())
@@ -151,10 +160,8 @@ class SingleNodeHTAP:
         if self.olap_mode == "ssi+safesnapshots":
             return self.engine.begin_deferred()   # None => reader-wait
         # ssi+rss: wait-free protected read over the freshest constructed RSS
-        t0 = tick()
-        with TRACER.span("route", policy="prot"):
+        with span("route", _ROUTE_H, policy="prot"):
             rid, snap = self.prot.acquire()
-        tock(_ROUTE_H, t0)
         t = self.engine.begin(read_only=True, rss=snap)
         self._pins[t.tid] = rid
         return t
@@ -172,8 +179,9 @@ class SingleNodeHTAP:
         vectorized pass.  With `check_scans`, every result is asserted
         equal to the per-key engine read path (`apply_plan` oracle)."""
         kind = type(plan).__name__
-        t0 = tick()
-        with TRACER.span("olap_serve", facade="single", plan=kind):
+        hist = _serve_hist(self._serve_h, (kind,), facade="single",
+                           plan=kind)
+        with span("olap_serve", hist, facade="single", plan=kind):
             if self.paged_store is not None and t.rss is not None:
                 self.engine._check_active(t)
                 result, writers = self.paged_store.execute_with_writers(
@@ -181,8 +189,6 @@ class SingleNodeHTAP:
                 self.engine.record_scan(t, plan_keys(plan), writers)
             else:
                 result = self.engine.execute(t, plan)
-        tock(_serve_hist(self._serve_h, (kind,), facade="single",
-                         plan=kind), t0)
         if self.check_scans:
             # per-key oracle parity (history suppressed: the read set was
             # already recorded by the plan execution above, and the check
@@ -219,15 +225,14 @@ class SingleNodeHTAP:
             self.engine._check_active(t)
         snap = entries[0][0].rss
         batch = BatchPlan(tuple(p for _, p in entries))
-        t0 = tick()
-        with TRACER.span("olap_serve", facade="single", plan="BatchPlan",
-                         fused=len(entries)):
-            results, writers = self.paged_store.execute_with_writers(batch,
-                                                                     snap)
         # one observation per fused dispatch: histogram count stays equal
         # to the number of serve-path executions, not member plans
-        tock(_serve_hist(self._serve_h, ("BatchPlan",), facade="single",
-                         plan="BatchPlan"), t0)
+        hist = _serve_hist(self._serve_h, ("BatchPlan",), facade="single",
+                           plan="BatchPlan")
+        with span("olap_serve", hist, facade="single", plan="BatchPlan",
+                  fused=len(entries)):
+            results, writers = self.paged_store.execute_with_writers(batch,
+                                                                     snap)
         off = 0
         for (t, p), result in zip(entries, results):
             pk = plan_keys(p)
@@ -311,25 +316,29 @@ class Replica:
         # publishers stay < K-1 versions ahead per page — the K-slot
         # staleness bound.
         gc_floor = self.gc_floor_seq()
-        for rec in primary.wal.tail(self.applied_lsn):
-            if max_records and n >= max_records:
-                break
-            self.applied_lsn = rec.lsn
-            if self.rss_manager is not None:
-                self.rss_manager.apply(rec)
-            if self.mirror is not None:
-                self.mirror.apply(rec, gc_floor=gc_floor)
-            if rec.type == "commit":
-                # the shared WAL commit clock (effective_commit_seq), so
-                # manager/mirror/store version stamps agree and installs
-                # stay strictly monotone even across mixed record kinds
-                seq = effective_commit_seq(self.applied_seq, rec.seq)
-                for key, value in rec.writes:
-                    self.store.chain(key).install(seq, rec.txn, value)
-                self.applied_seq = seq
-            n += 1
+        with span("ship_replay", _SHIP_REPLAY_H, node="replica"):
+            for rec in primary.wal.tail(self.applied_lsn):
+                if max_records and n >= max_records:
+                    break
+                self.applied_lsn = rec.lsn
+                if self.rss_manager is not None:
+                    self.rss_manager.apply(rec)
+                if self.mirror is not None:
+                    self.mirror.apply(rec, gc_floor=gc_floor)
+                if rec.type == "commit":
+                    # the shared WAL commit clock (effective_commit_seq),
+                    # so manager/mirror/store version stamps agree and
+                    # installs stay strictly monotone even across mixed
+                    # record kinds
+                    seq = effective_commit_seq(self.applied_seq, rec.seq)
+                    for key, value in rec.writes:
+                        self.store.chain(key).install(seq, rec.txn, value)
+                    self.applied_seq = seq
+                n += 1
         if self.rss_manager is not None and n:
-            snap = self.rss_manager.construct()
+            with span("rss_construct", _RSS_CONSTRUCT_H["replica"],
+                      node="replica"):
+                snap = self.rss_manager.construct()
             if self.mirror is not None:
                 # views advance with the delta ship, at the snapshot the
                 # fresh construct admits
@@ -382,7 +391,8 @@ class Replica:
     def gc_versions(self) -> int:
         """Prune replica-side chain versions below the pinned floor
         (hot_standby_feedback analogue on the replica's own store)."""
-        return self.store.prune(self.gc_floor_seq())
+        with span("gc_prune", GC_PRUNE_H["replica"], node="replica"):
+            return self.store.prune(self.gc_floor_seq())
 
     def read_si(self, snapshot_seq: int, key: str) -> Any:
         return self.version_store.read_at(key, snapshot_seq)
@@ -491,13 +501,11 @@ class MultiNodeHTAP:
         replica that served the handle's snapshot — the same
         freshness-policy decision as the acquisition."""
         kind, idx = type(plan).__name__, snap[1]
-        t0 = tick()
-        with TRACER.span("olap_serve", facade="multi", plan=kind,
-                         replica=idx):
-            result = self.cluster.execute(snap, plan)
-        tock(_serve_hist(self._serve_h, (kind, idx), facade="multi",
-                         plan=kind, replica=idx), t0)
-        return result
+        hist = _serve_hist(self._serve_h, (kind, idx), facade="multi",
+                           plan=kind, replica=idx)
+        with span("olap_serve", hist, facade="multi", plan=kind,
+                  replica=idx):
+            return self.cluster.execute(snap, plan)
 
     def olap_execute_batch(self, entries: Sequence[tuple]) -> list[Any]:
         """Cross-reader whole-batch plan fusion, cluster-routed: `entries`
@@ -523,13 +531,11 @@ class MultiNodeHTAP:
             return [self.olap_execute(h, p) for h, p in entries]
         batch = BatchPlan(tuple(p for _, p in entries))
         idx = entries[0][0][1]
-        t0 = tick()
-        with TRACER.span("olap_serve", facade="multi", plan="BatchPlan",
-                         replica=idx, fused=len(entries)):
-            results = list(self.cluster.execute(entries[0][0], batch))
-        tock(_serve_hist(self._serve_h, ("BatchPlan", idx), facade="multi",
-                         plan="BatchPlan", replica=idx), t0)
-        return results
+        hist = _serve_hist(self._serve_h, ("BatchPlan", idx), facade="multi",
+                           plan="BatchPlan", replica=idx)
+        with span("olap_serve", hist, facade="multi", plan="BatchPlan",
+                  replica=idx, fused=len(entries)):
+            return list(self.cluster.execute(entries[0][0], batch))
 
     def olap_release(self, snap) -> None:
         self.cluster.release(snap)

@@ -8,7 +8,10 @@ makes First-Committer-Wins the natural SI-W rule).
 
 GC: `prune(floor_seq)` drops versions strictly older than the newest version
 at-or-below `floor_seq` per key — the replica/PRoT pin (hot_standby_feedback
-analogue) sets the floor.
+analogue) sets the floor.  Each pass adds the chains it visited and the
+chains that dropped at least one version to the `gc_chains_visited` /
+`gc_chains_pruned` counters, once per pass; its callers time it as the
+`gc_prune` span (`GC_PRUNE_H`, labelled by node).
 """
 
 from __future__ import annotations
@@ -16,6 +19,15 @@ from __future__ import annotations
 from bisect import bisect_right
 from dataclasses import dataclass
 from typing import Any, Callable, Iterable, Iterator, Optional
+
+from ..obs import REGISTRY
+
+_CHAINS_VISITED = REGISTRY.counter("gc_chains_visited")
+_CHAINS_PRUNED = REGISTRY.counter("gc_chains_pruned")
+# one GC pass over a node's chain store (`Engine.prune_versions` on the
+# primary, `Replica.gc_versions` on a replica)
+GC_PRUNE_H = {node: REGISTRY.histogram("gc_prune_seconds", node=node)
+              for node in ("primary", "replica")}
 
 
 @dataclass(frozen=True)
@@ -84,7 +96,10 @@ class Store:
                    default=0)
 
     def prune(self, floor_seq: int) -> int:
-        return sum(c.prune(floor_seq) for c in self.chains.values())
+        dropped = [c.prune(floor_seq) for c in self.chains.values()]
+        _CHAINS_VISITED.inc(len(dropped))
+        _CHAINS_PRUNED.inc(len(dropped) - dropped.count(0))
+        return sum(dropped)
 
     def version_count(self) -> int:
         return sum(len(c.versions) for c in self.chains.values())

@@ -4,7 +4,9 @@ span tracing.
 Everything operational in the repo reports here: counters/gauges are
 always on (one attribute add each), latency histograms are on by default
 and stubbable via `set_timing(False)`, span capture is off by default
-and enabled with REPRO_TRACE=1 (or `TRACER.set_enabled(True)`).
+and enabled with REPRO_TRACE=1 (or `TRACER.set_enabled(True)`).  Timed
+stages go through `span(name, hist, **labels)`, which feeds all three
+and, under a live JAX profiler, the trace's host plane as `repro:<name>`.
 
 `reset_run()` is the one atomic "start a fresh measurement window"
 entry point the driver calls per run.
@@ -14,13 +16,13 @@ from .registry import (DEFAULT_BOUNDS, REGISTRY, Counter, CounterList, Gauge,
                        Histogram, LabeledCounterMap, MetricRegistry,
                        StatsView, set_timing, summarize, tick,
                        timing_enabled, tock)
-from .trace import TRACER, Span, Tracer
+from .trace import PROFILER_PREFIX, TRACER, Span, Tracer, span
 
 __all__ = [
     "Counter", "CounterList", "DEFAULT_BOUNDS", "Gauge", "Histogram",
-    "LabeledCounterMap", "MetricRegistry", "REGISTRY", "Span", "StatsView",
-    "TRACER", "Tracer", "reset_run", "set_timing", "summarize", "tick",
-    "timing_enabled", "tock",
+    "LabeledCounterMap", "MetricRegistry", "PROFILER_PREFIX", "REGISTRY",
+    "Span", "StatsView", "TRACER", "Tracer", "reset_run", "set_timing",
+    "span", "summarize", "tick", "timing_enabled", "tock",
 ]
 
 

@@ -29,10 +29,11 @@ Latency histograms use fixed log-spaced bucket boundaries (1 µs .. 10 s,
 linear interpolation inside the covering bucket — bounded memory at any
 sample count.
 
-Timing is cheap-by-default and stubbable: instrument with
-``t0 = tick()`` ... ``tock(hist, t0)``; `set_timing(False)` turns both
-into no-ops (no `perf_counter` calls), which is how the observability
-bench measures its own overhead bound.
+Timing is cheap-by-default and stubbable: stages are instrumented with
+``with span(name, hist): ...`` (`repro.obs.trace`), which times through
+`tick()` / `tock()`; `set_timing(False)` turns both into no-ops (no
+`perf_counter` calls), which is how the observability bench measures its
+own overhead bound.
 """
 
 from __future__ import annotations
@@ -240,11 +241,18 @@ class MetricRegistry:
 
     def totals(self) -> dict:
         """Counter/gauge families aggregated over all label sets — the
-        compact cross-instance view driver metrics snapshot from."""
+        compact cross-instance view driver metrics snapshot from — plus,
+        for every histogram family, `<family>_sum` (seconds) and
+        `<family>_count`, Prometheus's own suffixes."""
         with self._lock:
-            out: dict[str, int] = {}
+            out: dict = {}
             for m in self._metrics.values():
-                if m.kind in ("counter", "gauge"):
+                if m.kind == "histogram":
+                    out[m.name + "_sum"] = out.get(m.name + "_sum", 0.0) \
+                        + m.total
+                    out[m.name + "_count"] = \
+                        out.get(m.name + "_count", 0) + m.count
+                else:
                     out[m.name] = out.get(m.name, 0) + m.value
             return out
 

@@ -1,7 +1,8 @@
-"""Lightweight span tracing of the two hot paths.
+"""Lightweight span tracing of the hot paths, and `span()`: the one
+instrumentation primitive every timed stage goes through.
 
 A *span* is a named, labeled, timed tree node: the OLAP serve path opens
-`olap_serve` with children for route -> resolve -> kernel dispatch ->
+`olap_serve` with children for route -> resolve -> dispatch ->
 finalize, and the OLTP commit path opens `oltp_commit` with certify/WAL
 children — so a trace dump answers "where did this serve spend its
 time?" per replica / policy / plan kind / kernel mode.
@@ -15,6 +16,14 @@ perf_counter pairs and small dicts: no I/O, no thread handoff.
 The tracer also keeps always-on `spans_opened` / `spans_closed`
 registry counters (balance is a verify.sh invariant: an unbalanced tree
 means an instrumented path raised past its finally or a span leaked).
+
+`span(name, hist, **labels)` joins the three clocks a stage can report
+to: it observes `hist` (always on, stubbable by `set_timing(False)`, and
+only when the block exits normally — a commit that aborts is no commit),
+opens a `TRACER` node when capture is on, and enters a
+`jax.profiler.TraceAnnotation` named ``repro:<name>`` only while a
+profiler session is live, so program stages land on the device trace's
+host plane nested inside whatever annotation the caller opened.
 """
 
 from __future__ import annotations
@@ -24,7 +33,11 @@ import time
 from collections import deque
 from typing import Optional
 
-from .registry import REGISTRY
+from jax.profiler import TraceAnnotation as _Annotation
+
+from .registry import REGISTRY, Histogram, tick, tock
+
+PROFILER_PREFIX = "repro:"
 
 _FALSE = ("0", "false", "no", "off")
 
@@ -167,3 +180,42 @@ class Tracer:
 
 # the process-wide default tracer
 TRACER = Tracer()
+
+
+class _StageSpan:
+    """What `span()` returns: a histogram timer, a tracer node and a
+    profiler annotation entered and left together."""
+
+    __slots__ = ("_hist", "_node", "_ann", "_t0")
+
+    def __init__(self, name: str, hist: Optional[Histogram],
+                 labels: dict) -> None:
+        self._hist = hist
+        self._node = TRACER.span(name, **labels)
+        self._ann = _Annotation(PROFILER_PREFIX + name) \
+            if _Annotation.is_enabled() else None
+        self._t0 = 0.0
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        node = self._node.__enter__()
+        if self._hist is not None:
+            self._t0 = tick()
+        return node
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._hist is not None and exc_type is None:
+            tock(self._hist, self._t0)
+        self._node.__exit__(exc_type, exc, tb)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+def span(name: str, hist: Optional[Histogram] = None, **labels) -> _StageSpan:
+    """Time one stage: ``with span("resolve", RESOLVE_H, plan=kind): ...``.
+    `hist` (optional) observes the block's seconds on a normal exit;
+    `labels` go to the tracer node only.  Names never start with the
+    benchmark's own annotation prefix."""
+    return _StageSpan(name, hist, labels)

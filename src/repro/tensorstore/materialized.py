@@ -169,6 +169,7 @@ class MaterializedView:
             self.shadow[lane] = row
             if not self.degraded:
                 tile[lane, :7] = row
+        self.mirror.h2d_bytes.inc(tile.nbytes)
         self.acc = jnp.asarray(tile)
         self._key_seq.clear()
         self.pending = []
@@ -249,6 +250,7 @@ class MaterializedView:
         delta = np.zeros((dp, 128), np.int32)
         delta[:, 0] = -1                        # padding rows fold nowhere
         delta[:len(self.pending), :6] = np.asarray(self.pending, np.int32)
+        self.mirror.h2d_bytes.inc(delta.nbytes)
         self.acc = kops.delta_fold(self.acc, delta,
                                    use_kernel=self.use_kernel,
                                    interpret=self.interpret)

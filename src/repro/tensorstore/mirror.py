@@ -39,7 +39,7 @@ import numpy as np
 
 from ..core.replica import RssSnapshot
 from ..core.wal import Wal, WalRecord, effective_commit_seq
-from ..obs import REGISTRY, TRACER, StatsView, tick, tock
+from ..obs import REGISTRY, TRACER, StatsView, span
 
 # serve-path per-stage latency: visibility resolve, kernel dispatch, and
 # result fold/finalize (the route stage is observed by the facades /
@@ -47,6 +47,17 @@ from ..obs import REGISTRY, TRACER, StatsView, tick, tock
 _RESOLVE_H = REGISTRY.histogram("olap_stage_seconds", stage="resolve")
 _DISPATCH_H = REGISTRY.histogram("olap_stage_seconds", stage="dispatch")
 _FINALIZE_H = REGISTRY.histogram("olap_stage_seconds", stage="finalize")
+# parts of the dispatch stage, and the mirror's refresh-side stages: one
+# `<span>_seconds` family each.  serve_upload is the host gather and the
+# copy to the device of a scanned sub-store (a `_store_for` miss);
+# serve_kernel runs from a kernel launch to its folded result (it holds
+# `field_maxabs`, the `serve_maxabs` span of the kernel ops); serve_host_agg
+# is the host-mode decode and aggregate.
+_UPLOAD_H = REGISTRY.histogram("serve_upload_seconds")
+_KERNEL_H = REGISTRY.histogram("serve_kernel_seconds")
+_HOST_AGG_H = REGISTRY.histogram("serve_host_agg_seconds")
+_CATCH_UP_H = REGISTRY.histogram("mirror_catch_up_seconds")
+_VIEW_ADVANCE_H = REGISTRY.histogram("view_advance_seconds")
 
 # payload tags (element 0 of every page payload)
 TAG_INIT = 0        # never-written page: decodes to the initial value 0
@@ -181,6 +192,9 @@ class PagedMirror:
         lbl = {"mirror": REGISTRY.scope("mirror")}
         self.range_stats = StatsView(REGISTRY, "mirror_range",
                                      ("dense", "gather"), labels=lbl)
+        # bytes of every host array a serve or view path copies to the
+        # device, counted once per copy at the call that makes it
+        self.h2d_bytes = REGISTRY.counter("mirror_h2d_bytes", **lbl)
         # grouped-strategy override (None = shape dispatch; "host" /
         # "flat" / "chunked" forces a mode — tests and benches pin it)
         self.grouped_mode: str | None = None
@@ -352,9 +366,10 @@ class PagedMirror:
     def catch_up(self, wal: Wal, *, gc_floor: int = 0) -> int:
         """Pull and apply all records past applied_lsn; returns #applied."""
         n = 0
-        for rec in wal.tail(self.applied_lsn):
-            self.apply(rec, gc_floor=gc_floor)
-            n += 1
+        with span("mirror_catch_up", _CATCH_UP_H):
+            for rec in wal.tail(self.applied_lsn):
+                self.apply(rec, gc_floor=gc_floor)
+                n += 1
         return n
 
     # ------------------------------------------------- materialized views
@@ -430,17 +445,18 @@ class PagedMirror:
         for unresolved dependencies wait their turn."""
         if not self.views or not self._unfolded:
             return 0
-        visible = self._visible_fn(snapshot)
-        keep, folded = [], 0
-        for seq, rec in self._unfolded:
-            if visible(seq):
-                for view in self.views.values():
-                    view.on_commit(rec, seq)
-                bisect.insort(self._folded_seqs, seq)
-                folded += 1
-            else:
-                keep.append((seq, rec))
-        self._unfolded = keep
+        with span("view_advance", _VIEW_ADVANCE_H):
+            visible = self._visible_fn(snapshot)
+            keep, folded = [], 0
+            for seq, rec in self._unfolded:
+                if visible(seq):
+                    for view in self.views.values():
+                        view.on_commit(rec, seq)
+                    bisect.insort(self._folded_seqs, seq)
+                    folded += 1
+                else:
+                    keep.append((seq, rec))
+            self._unfolded = keep
         return folded
 
     def view_gate(self, snapshot) -> bool:
@@ -484,18 +500,14 @@ class PagedMirror:
                 or not self.view_gate(snapshot)):
             self.exec_stats["view_fallbacks"] += n_reg
             return None
-        t0 = tick()
-        with TRACER.span("view_serve", plans=len(views)):
+        with span("view_serve", _DISPATCH_H, plans=len(views)):
             results = [v.result() for v in views]
-        tock(_DISPATCH_H, t0)
         if need_writers:
-            t0 = tick()
-            with TRACER.span("resolve"):
+            with span("resolve", _RESOLVE_H):
                 all_keys = [k for p in plans for k in plan_keys(p)]
                 mask_fn, _m, _f = self._snapshot_mask(snapshot)
                 writers = self._writers_for(self.page_index(all_keys),
                                             mask_fn)
-            tock(_RESOLVE_H, t0)
         else:
             writers = []
         self.exec_stats["view_hits"] += len(views)
@@ -698,22 +710,25 @@ class PagedMirror:
         rng = as_page_range(pages)
         self._last_range_verdict = "dense" if rng is not None else "gather"
         self.range_stats[self._last_range_verdict] += 1
-        if rng is not None:
-            data, ts = self.data[rng[0]:rng[1]], self.ts[rng[0]:rng[1]]
-        else:
-            safe = np.where(pages >= 0, pages, 0)
-            data, ts = self.data[safe], self.ts[safe]
-            miss = pages < 0
-            if miss.any():
-                data[miss] = 0
-                ts[miss] = 0
-        if pad:
-            pd = np.zeros((pad,) + self.data.shape[1:], np.int32)
-            pd[:, :, 0] = TAG_PAD
-            data = np.concatenate([data, pd])
-            ts = np.concatenate(
-                [ts, np.zeros((pad,) + self.ts.shape[1:], np.int32)])
-        return {"data": jnp.asarray(data), "ts": jnp.asarray(ts)}
+        with span("serve_upload", _UPLOAD_H,
+                  verdict=self._last_range_verdict):
+            if rng is not None:
+                data, ts = self.data[rng[0]:rng[1]], self.ts[rng[0]:rng[1]]
+            else:
+                safe = np.where(pages >= 0, pages, 0)
+                data, ts = self.data[safe], self.ts[safe]
+                miss = pages < 0
+                if miss.any():
+                    data[miss] = 0
+                    ts[miss] = 0
+            if pad:
+                pd = np.zeros((pad,) + self.data.shape[1:], np.int32)
+                pd[:, :, 0] = TAG_PAD
+                data = np.concatenate([data, pd])
+                ts = np.concatenate(
+                    [ts, np.zeros((pad,) + self.ts.shape[1:], np.int32)])
+            self.h2d_bytes.inc(data.nbytes + ts.nbytes)
+            return {"data": jnp.asarray(data), "ts": jnp.asarray(ts)}
 
     def _scalar_raws(self, pages: np.ndarray, member_ts, floor, ops, *,
                      keys: Sequence[str] | None = None,
@@ -736,9 +751,12 @@ class PagedMirror:
         raws = {}
         for field, thr in configs:
             tag_main, tag_alt = AGG_FIELD_TAGS[field]
-            raws[(field, thr)] = snapshot_agg_members(
-                store, mem, floor, tag_main=tag_main, tag_alt=tag_alt,
-                threshold=thr, use_kernel=use_kernel, interpret=interpret)
+            with span("serve_kernel", _KERNEL_H, field=field):
+                self.h2d_bytes.inc(mem.nbytes)     # the member array
+                raws[(field, thr)] = snapshot_agg_members(
+                    store, mem, floor, tag_main=tag_main, tag_alt=tag_alt,
+                    threshold=thr, use_kernel=use_kernel,
+                    interpret=interpret)
         return raws
 
     def _grouped_rows(self, lane_groups, lane_params, mask_fn, member_ts,
@@ -765,8 +783,8 @@ class PagedMirror:
             len(flat_keys), len(lane_groups), n_plans,
             override=self.grouped_mode)
         if mode == "host":
-            with TRACER.span("kernel_dispatch", mode="host",
-                             lanes=len(lane_groups)):
+            with span("serve_host_agg", _HOST_AGG_H,
+                      lanes=len(lane_groups)):
                 kops.LAUNCH_STATS["dispatches"] += 1
                 kops.LAUNCH_STATS["host"] += 1
                 self.exec_stats["mode_host"] += 1
@@ -785,20 +803,21 @@ class PagedMirror:
                                  sum(1 for x in xs if x > thr_eff),
                                  sum(x for x in xs if x < thr_eff)])
                 return rows
-        with TRACER.span("kernel_dispatch", lanes=len(lane_groups)):
-            flat_keys = tuple(flat_keys)
-            pages = self.page_index(flat_keys)
-            store = self._store_for(flat_keys, pages)
-            gid = np.full(int(store["ts"].shape[0]), -1, np.int32)
-            gid[:len(pages)] = np.concatenate(
-                [np.full(len(grp), g, np.int32)
-                 for g, grp in enumerate(lane_groups)])
-            gparams = np.asarray(
-                [[tm, ta, int(_INT32.max) if thr is None else int(thr)]
-                 for _f, tm, ta, thr in lane_params], np.int32)
+        flat_keys = tuple(flat_keys)
+        pages = self.page_index(flat_keys)
+        store = self._store_for(flat_keys, pages)
+        gid = np.full(int(store["ts"].shape[0]), -1, np.int32)
+        gid[:len(pages)] = np.concatenate(
+            [np.full(len(grp), g, np.int32)
+             for g, grp in enumerate(lane_groups)])
+        gparams = np.asarray(
+            [[tm, ta, int(_INT32.max) if thr is None else int(thr)]
+             for _f, tm, ta, thr in lane_params], np.int32)
+        mem = np.asarray(member_ts, np.int32)
+        with span("serve_kernel", _KERNEL_H, lanes=len(lane_groups)):
+            self.h2d_bytes.inc(gid.nbytes + gparams.nbytes + mem.nbytes)
             rows, used = kops.grouped_agg_auto(
-                store, gid, len(lane_groups),
-                np.asarray(member_ts, np.int32), floor,
+                store, gid, len(lane_groups), mem, floor,
                 group_params=gparams, n_plans=n_plans, mode=mode,
                 use_kernel=use_kernel, interpret=interpret)
             TRACER.annotate(mode=used)
@@ -816,36 +835,33 @@ class PagedMirror:
                                     finalize_agg, plan_keys)
 
         lane_groups, lane_params, lane_of = self._lane_layout_for(plans)
-        t0 = tick()
-        with TRACER.span("resolve"):
+        with span("resolve", _RESOLVE_H):
             mask_fn, member_ts, floor = self._snapshot_mask(snapshot)
             all_keys = [k for p in plans for k in plan_keys(p)]
             writers = self._writers_for(self.page_index(all_keys), mask_fn)
-        tock(_RESOLVE_H, t0)
-        t0 = tick()
-        rows = self._grouped_rows(lane_groups, lane_params, mask_fn,
-                                  member_ts, floor, len(plans),
-                                  use_kernel=use_kernel,
-                                  interpret=interpret)
-        tock(_DISPATCH_H, t0)
-        t0 = tick()
-        results = []
-        for p_i, plan in enumerate(plans):
-            if isinstance(plan, GroupByPlan):
-                results.append(tuple(
-                    tuple(finalize_agg(
-                        rows[lane_of[(p_i, _op_config(op), g)]], op)
-                        for op in plan.ops)
-                    for g in range(len(plan.key_groups))))
-            elif isinstance(plan, MultiAggPlan):
-                results.append(tuple(finalize_agg(
-                    rows[lane_of[(p_i, _op_config(op), 0)]], op)
-                    for op in plan.ops))
-            else:
-                assert isinstance(plan, AggPlan), plan
-                results.append(finalize_agg(
-                    rows[lane_of[(p_i, _op_config(plan.op), 0)]], plan.op))
-        tock(_FINALIZE_H, t0)
+        with span("dispatch", _DISPATCH_H, mode="grouped"):
+            rows = self._grouped_rows(lane_groups, lane_params, mask_fn,
+                                      member_ts, floor, len(plans),
+                                      use_kernel=use_kernel,
+                                      interpret=interpret)
+        with span("finalize", _FINALIZE_H):
+            results = []
+            for p_i, plan in enumerate(plans):
+                if isinstance(plan, GroupByPlan):
+                    results.append(tuple(
+                        tuple(finalize_agg(
+                            rows[lane_of[(p_i, _op_config(op), g)]], op)
+                            for op in plan.ops)
+                        for g in range(len(plan.key_groups))))
+                elif isinstance(plan, MultiAggPlan):
+                    results.append(tuple(finalize_agg(
+                        rows[lane_of[(p_i, _op_config(op), 0)]], op)
+                        for op in plan.ops))
+                else:
+                    assert isinstance(plan, AggPlan), plan
+                    results.append(finalize_agg(
+                        rows[lane_of[(p_i, _op_config(plan.op), 0)]],
+                        plan.op))
         return results, writers
 
     def execute_with_writers(self, plan, snapshot, *,
@@ -874,17 +890,16 @@ class PagedMirror:
                                     MultiAggPlan, ScanPlan, finalize_agg,
                                     plan_keys)
 
-        with TRACER.span("mirror_execute", plan=type(plan).__name__):
+        with span("mirror_execute", plan=type(plan).__name__):
             if self.views and not isinstance(plan, ScanPlan):
                 served = self._try_views(plan, snapshot, need_writers)
                 if served is not None:
                     return served
             if isinstance(plan, ScanPlan):
                 self.exec_stats["plans"] += 1
-                t0 = tick()
-                out = self.scan_with_writers(plan.keys, snapshot)
-                tock(_RESOLVE_H, t0)       # a scan IS its visibility resolve
-                return out
+                # a scan IS its visibility resolve
+                with span("resolve", _RESOLVE_H, plan="ScanPlan"):
+                    return self.scan_with_writers(plan.keys, snapshot)
             if isinstance(plan, BatchPlan):
                 self.exec_stats["plans"] += len(plan.plans)
                 self.exec_stats["batches"] += 1
@@ -900,24 +915,18 @@ class PagedMirror:
                     interpret=interpret)
                 return results[0], writers
             keys = plan_keys(plan)
-            t0 = tick()
-            with TRACER.span("resolve"):
+            with span("resolve", _RESOLVE_H):
                 pages = self.page_index(keys)
                 mask_fn, member_ts, floor = self._snapshot_mask(snapshot)
                 writers = self._writers_for(pages, mask_fn)
-            tock(_RESOLVE_H, t0)
             ops = (plan.op,) if isinstance(plan, AggPlan) else plan.ops
-            t0 = tick()
-            with TRACER.span("kernel_dispatch", mode="scalar",
-                             configs=len(set(_op_config(op) for op in ops))):
+            with span("dispatch", _DISPATCH_H, mode="scalar"):
                 raws = self._scalar_raws(pages, member_ts, floor, ops,
                                          keys=keys, use_kernel=use_kernel,
                                          interpret=interpret)
-            tock(_DISPATCH_H, t0)
-            t0 = tick()
-            vals = tuple(finalize_agg(raws[_op_config(op)], op)
-                         for op in ops)
-            tock(_FINALIZE_H, t0)
+            with span("finalize", _FINALIZE_H):
+                vals = tuple(finalize_agg(raws[_op_config(op)], op)
+                             for op in ops)
             if isinstance(plan, AggPlan):
                 return vals[0], writers
             assert isinstance(plan, MultiAggPlan), plan
@@ -938,4 +947,5 @@ class PagedMirror:
         ts = self.ts[:p + pad] if p + pad <= self.ts.shape[0] else \
             np.concatenate([self.ts[:p],
                             np.zeros((pad,) + self.ts.shape[1:], np.int32)])
+        self.h2d_bytes.inc(data.nbytes + ts.nbytes)
         return {"data": jnp.asarray(data), "ts": jnp.asarray(ts)}

@@ -332,3 +332,186 @@ def test_cross_layer_invariants_multi_node(batching):
     # the route stage is the cluster acquire path
     assert m.serve_stage_latency["route"]["count"] == \
         REGISTRY.total("cluster_acquires") > 0
+
+
+# ------------------------------------------------- the span() primitive
+def test_span_observes_hist_and_records_tree_node():
+    from repro.obs import span
+    reg = MetricRegistry()
+    outer_h, inner_h = reg.histogram("a_seconds"), reg.histogram("b_seconds")
+    TRACER.set_enabled(True)
+    TRACER.clear()
+    try:
+        with span("outer", outer_h, plan="AggPlan") as root:
+            with span("inner", inner_h):
+                TRACER.annotate(mode="flat")
+            with span("untimed"):
+                pass
+        assert outer_h.count == inner_h.count == 1
+        assert outer_h.total >= inner_h.total > 0
+        assert root.name == "outer" and root.labels == {"plan": "AggPlan"}
+        assert [c.name for c in root.children] == ["inner", "untimed"]
+        assert root.children[0].labels == {"mode": "flat"}
+        # an exception leaves the histogram unobserved (an aborted commit
+        # is no commit) and the tree balanced
+        with pytest.raises(ValueError):
+            with span("outer", outer_h):
+                raise ValueError("abort")
+        assert outer_h.count == 1 and TRACER.depth == 0
+        assert TRACER.opened == TRACER.closed
+    finally:
+        TRACER.set_enabled(None)
+        TRACER.clear()
+
+
+def test_span_times_when_tracing_is_off_and_not_when_stubbed():
+    from repro.obs import span
+    reg = MetricRegistry()
+    h = reg.histogram("c_seconds")
+    TRACER.set_enabled(False)
+    try:
+        with span("stage", h, k=1) as node:
+            assert TRACER.depth == 0
+        assert h.count == 1 and node is not None
+        set_timing(False)
+        try:
+            with span("stage", h):
+                pass
+        finally:
+            set_timing(True)
+        assert h.count == 1
+    finally:
+        TRACER.set_enabled(None)
+
+
+def _host_events(log_dir):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    return [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for ln in plane.lines for e in ln.events]
+
+
+def _covers(outer, inner) -> bool:
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_span_enters_the_profiler_trace_only_when_it_runs(tmp_path):
+    """Outside a profiler session a span holds no annotation; inside one,
+    `repro:<name>` events land on the host plane, nested as the spans
+    were and inside the caller's own annotation, over a whole serve and
+    commit path."""
+    import jax
+
+    from repro.obs import PROFILER_PREFIX, span
+    from repro.obs.trace import _Annotation
+    assert not _Annotation.is_enabled()
+    assert span("stage")._ann is None
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("bench:phase"):
+            with span("outer"):
+                with span("inner", REGISTRY.histogram("probe_seconds")):
+                    pass
+            run_single_node(olap_mode="ssi+rss", oltp_clients=2,
+                            olap_clients=2, rounds=40, seed=5,
+                            olap_scan=True, paged_olap=True)
+    finally:
+        jax.profiler.stop_trace()
+    evs = _host_events(str(tmp_path))
+    phase = [e for e in evs if e[2] == "bench:phase"]
+    ours = [e for e in evs if e[2].startswith(PROFILER_PREFIX)]
+    assert len(phase) == 1 and ours
+    # the program never emits the benchmark's own phase prefix
+    assert [e[2] for e in evs if e[2].startswith("bench:")] == ["bench:phase"]
+    assert all(_covers(phase[0], e) for e in ours)
+    (outer,) = [e for e in ours if e[2] == "repro:outer"]
+    (inner,) = [e for e in ours if e[2] == "repro:inner"]
+    assert _covers(outer, inner)
+    names = {e[2][len(PROFILER_PREFIX):] for e in ours}
+    assert {"olap_serve", "route", "mirror_execute", "resolve", "dispatch",
+            "finalize", "oltp_commit", "certify", "wal_emit",
+            "rss_construct", "mirror_catch_up"} <= names
+    serves = [e for e in ours if e[2] == "repro:olap_serve"]
+    for e in ours:
+        if e[2] in ("repro:resolve", "repro:dispatch", "repro:finalize"):
+            assert any(_covers(s, e) for s in serves), e
+    commits = [e for e in ours if e[2] == "repro:oltp_commit"]
+    for e in ours:
+        if e[2] in ("repro:certify", "repro:wal_emit"):
+            assert any(_covers(c, e) for c in commits), e
+
+
+def test_totals_carry_histogram_sum_and_count():
+    reg = MetricRegistry()
+    reg.counter("n_total").inc(2)
+    a = reg.histogram("x_seconds", node="primary")
+    b = reg.histogram("x_seconds", node="replica")
+    a.observe(0.25)
+    b.observe(0.5)
+    b.observe(0.25)
+    tot = reg.totals()
+    assert tot["n_total"] == 2
+    assert tot["x_seconds_count"] == 3
+    assert tot["x_seconds_sum"] == pytest.approx(1.0)
+    assert "x_seconds" not in tot
+    reg.reset()
+    assert reg.totals()["x_seconds_count"] == 0
+
+
+def test_gc_counters_count_chains_visited_and_pruned_once_per_pass():
+    from repro.mvcc.store import Store
+    st = Store()
+    st.chain("a").install(1, 1, 10)
+    st.chain("a").install(2, 2, 20)
+    st.chain("a").install(5, 3, 30)      # a: v0, 1, 2, 5
+    st.chain("b").install(4, 4, 40)      # b: v0, 4
+    st.chain("c")                        # c: v0 only
+    v0 = REGISTRY.total("gc_chains_visited")
+    p0 = REGISTRY.total("gc_chains_pruned")
+    assert st.prune(3) == 2              # a drops v0 and v1; b keeps v0
+    assert REGISTRY.total("gc_chains_visited") - v0 == 3
+    assert REGISTRY.total("gc_chains_pruned") - p0 == 1
+    assert st.prune(4) == 1              # b drops v0 now
+    assert REGISTRY.total("gc_chains_visited") - v0 == 6
+    assert REGISTRY.total("gc_chains_pruned") - p0 == 2
+
+
+def test_gc_passes_are_spans_by_node():
+    from repro.mvcc.htap import MultiNodeHTAP
+    htap = MultiNodeHTAP("ssi+rss", n_replicas=2)
+    t = htap.primary.begin()
+    htap.primary.write(t, "k", 1)
+    htap.primary.commit(t)
+    htap.ship_log()
+    before = {n: REGISTRY.hist_summary("gc_prune_seconds", node=n)["count"]
+              for n in ("primary", "replica")}
+    htap.gc_versions()
+    after = {n: REGISTRY.hist_summary("gc_prune_seconds", node=n)["count"]
+             for n in ("primary", "replica")}
+    assert after["primary"] - before["primary"] == 1
+    assert after["replica"] - before["replica"] == 2
+
+
+def test_h2d_bytes_count_an_upload_and_not_a_store_cache_hit():
+    from repro.core.wal import WalRecord
+    from repro.tensorstore import PagedMirror
+    mirror = PagedMirror()
+    mirror.apply(WalRecord(lsn=1, type="commit", txn=1,
+                           writes=(("a", 5), ("b", 9)), seq=1))
+    keys = ("a", "b")
+    pages = mirror.page_index(keys)
+    up0 = REGISTRY.hist_summary("serve_upload_seconds")["count"]
+    store = mirror._store_for(keys, pages)
+    sent = store["data"].nbytes + store["ts"].nbytes
+    assert mirror.h2d_bytes.value == sent > 0
+    assert mirror._store_for(keys, pages) is store       # a cache hit
+    assert mirror.h2d_bytes.value == sent
+    assert REGISTRY.hist_summary("serve_upload_seconds")["count"] == up0 + 1
