@@ -8,10 +8,15 @@ makes First-Committer-Wins the natural SI-W rule).
 
 GC: `prune(floor_seq)` drops versions strictly older than the newest version
 at-or-below `floor_seq` per key — the replica/PRoT pin (hot_standby_feedback
-analogue) sets the floor.  Each pass adds the chains it visited and the
-chains that dropped at least one version to the `gc_chains_visited` /
-`gc_chains_pruned` counters, once per pass; its callers time it as the
-`gc_prune` span (`GC_PRUNE_H`, labelled by node).
+analogue) sets the floor.  A chain holding a single version has nothing to
+drop, so a pass visits only the store's `candidates`: the chains holding two
+or more versions.  A chain a store created enters `candidates` from its own
+`install` when it reaches two versions, whichever path installed it (engine
+commit, replica WAL replay, a direct `chain(key).install`); a pass takes out
+every chain it leaves with one.  Each pass adds the chains it visited (the
+candidates) and those that dropped at least one version to the
+`gc_chains_visited` / `gc_chains_pruned` counters, once per pass; its
+callers time it as the `gc_prune` span (`GC_PRUNE_H`, labelled by node).
 """
 
 from __future__ import annotations
@@ -38,14 +43,22 @@ class Version:
 
 
 class VersionChain:
-    __slots__ = ("versions",)
+    __slots__ = ("versions", "key", "_candidates")
 
-    def __init__(self, initial: Any = 0) -> None:
+    def __init__(self, initial: Any = 0, key: Optional[str] = None,
+                 candidates: Optional[dict] = None) -> None:
+        """`key` and `candidates` come from the owning `Store`: the chain
+        enters `candidates[key]` when it reaches two versions.  A chain
+        made without them keeps no such record."""
         self.versions: list[Version] = [Version(0, 0, initial)]
+        self.key = key
+        self._candidates = candidates
 
     def install(self, commit_seq: int, writer: int, value: Any) -> None:
         assert commit_seq > self.versions[-1].commit_seq
         self.versions.append(Version(commit_seq, writer, value))
+        if len(self.versions) == 2 and self._candidates is not None:
+            self._candidates[self.key] = self
 
     def newest(self) -> Version:
         return self.versions[-1]
@@ -81,11 +94,15 @@ class VersionChain:
 class Store:
     def __init__(self) -> None:
         self.chains: dict[str, VersionChain] = {}
+        # the chains holding two or more versions, the only ones GC can
+        # shorten; kept by `VersionChain.install` and `prune`
+        self.candidates: dict[str, VersionChain] = {}
 
     def chain(self, key: str) -> VersionChain:
         ch = self.chains.get(key)
         if ch is None:
-            ch = self.chains[key] = VersionChain()
+            ch = self.chains[key] = VersionChain(
+                key=key, candidates=self.candidates)
         return ch
 
     def keys(self) -> Iterator[str]:
@@ -96,9 +113,15 @@ class Store:
                    default=0)
 
     def prune(self, floor_seq: int) -> int:
-        dropped = [c.prune(floor_seq) for c in self.chains.values()]
+        """Prune every candidate chain at `floor_seq`, take out of
+        `candidates` each one left with a single version, and return the
+        versions dropped.  Counts the candidates as the chains visited."""
+        cands = self.candidates
+        dropped = [c.prune(floor_seq) for c in cands.values()]
         _CHAINS_VISITED.inc(len(dropped))
         _CHAINS_PRUNED.inc(len(dropped) - dropped.count(0))
+        for key in [k for k, c in cands.items() if len(c.versions) == 1]:
+            del cands[key]
         return sum(dropped)
 
     def version_count(self) -> int:

@@ -473,15 +473,44 @@ def test_gc_counters_count_chains_visited_and_pruned_once_per_pass():
     st.chain("a").install(2, 2, 20)
     st.chain("a").install(5, 3, 30)      # a: v0, 1, 2, 5
     st.chain("b").install(4, 4, 40)      # b: v0, 4
-    st.chain("c")                        # c: v0 only
+    st.chain("c")                        # c: v0 only, never a candidate
     v0 = REGISTRY.total("gc_chains_visited")
     p0 = REGISTRY.total("gc_chains_pruned")
     assert st.prune(3) == 2              # a drops v0 and v1; b keeps v0
-    assert REGISTRY.total("gc_chains_visited") - v0 == 3
+    assert REGISTRY.total("gc_chains_visited") - v0 == 2
     assert REGISTRY.total("gc_chains_pruned") - p0 == 1
-    assert st.prune(4) == 1              # b drops v0 now
-    assert REGISTRY.total("gc_chains_visited") - v0 == 6
+    assert st.prune(4) == 1              # b drops v0 now; a: 2, 5 stays
+    assert REGISTRY.total("gc_chains_visited") - v0 == 4
     assert REGISTRY.total("gc_chains_pruned") - p0 == 2
+    assert set(st.candidates) == {"a"}   # b is left with one version
+
+
+def test_gc_never_visits_a_single_version_chain():
+    from repro.mvcc.store import Store
+    st = Store()
+    st.chain("c")                        # c: v0 only
+    st.chain("d").install(1, 1, 10)      # d: v0, 1
+    v0 = REGISTRY.total("gc_chains_visited")
+    assert st.prune(5) == 1              # d drops v0
+    assert REGISTRY.total("gc_chains_visited") - v0 == 1
+    assert not st.candidates
+    assert st.prune(9) == 0              # nothing left to visit
+    assert REGISTRY.total("gc_chains_visited") - v0 == 1
+
+
+def test_gc_pruned_chain_rejoins_candidates_at_next_install():
+    from repro.mvcc.store import Store
+    st = Store()
+    st.chain("a").install(1, 1, 10)
+    assert st.prune(1) == 1 and not st.candidates     # a: 1
+    st.chain("a").install(2, 2, 20)                   # a: 1, 2
+    assert st.candidates == {"a": st.chain("a")}
+    v0 = REGISTRY.total("gc_chains_visited")
+    p0 = REGISTRY.total("gc_chains_pruned")
+    assert st.prune(2) == 1
+    assert REGISTRY.total("gc_chains_visited") - v0 == 1
+    assert REGISTRY.total("gc_chains_pruned") - p0 == 1
+    assert [v.commit_seq for v in st.chain("a").versions] == [2]
 
 
 def test_gc_passes_are_spans_by_node():
