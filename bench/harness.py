@@ -8,7 +8,9 @@ The round loop and the clients are copies of `repro.mvcc.driver`
 analytic step is served by one `olap_execute` call.  The harness touches only the
 program's public surface: the facades `SingleNodeHTAP` / `MultiNodeHTAP`,
 `Engine` begin/read/write/commit, the plan IR, `refresh_rss`, `ship_log`,
-`gc_versions`, `repro.obs.reset_run` and `REGISTRY`.
+`gc_versions`, `repro.obs.reset_run` and `REGISTRY`.  The facade's options
+come from the configuration's own keys, and its optional `facade` object
+passes further ones (`facade_options`).
 
 It times the calls into each layer from here (`Spans`), and in a traced
 run wraps each phase of a round in a `jax.profiler.TraceAnnotation`, so the
@@ -22,6 +24,7 @@ import contextlib
 import importlib.util
 import json
 import random
+import threading
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -34,13 +37,33 @@ BENCH = Path(__file__).resolve().parent
 TRACE_PREFIX = "bench:"
 
 
+# the facades' options that `Run` sets from a configuration's own keys
+HARNESS_OPTIONS = frozenset({
+    "olap_mode", "reserve_keys", "materialize", "certifier", "resolve_cache",
+    "paged", "paged_olap", "n_replicas", "route_policy", "max_staleness"})
+
+
 # ------------------------------------------------------------------ files
 def load_config(name: str, root: Path = BENCH) -> dict:
     """The configuration `<root>/configs/<name>.json`."""
     cfg = json.loads((root / "configs" / f"{name}.json").read_text())
     if cfg["name"] != name:
         raise ValueError(f"config file {name}.json names {cfg['name']!r}")
+    facade_options(cfg)
     return cfg
+
+
+def facade_options(cfg: dict) -> dict:
+    """The configuration's `facade` object: further keyword arguments of
+    the facade's constructor.  An option that the harness sets itself from
+    the configuration's own keys is refused, so each has one place."""
+    opts = dict(cfg.get("facade", {}))
+    taken = sorted(HARNESS_OPTIONS & set(opts))
+    if taken:
+        raise ValueError(f"configuration {cfg['name']!r}: facade options "
+                         f"{taken} are set by the harness from the "
+                         f"configuration's own keys")
+    return opts
 
 
 def load_reader(metric: str, root: Path = BENCH):
@@ -111,20 +134,23 @@ class CompileClock:
     def __init__(self) -> None:
         import jax
         self._jax = jax
+        self._lock = threading.Lock()   # the warm-up compiles on threads
         self.reset()
         jax.monitoring.register_event_duration_secs_listener(self._on_dur)
         jax.monitoring.register_event_listener(self._on_event)
 
     def _on_dur(self, event: str, secs: float, **kw) -> None:
-        if event == self._COMPILE:
-            self.compiles += 1
-            self.names.append(str(kw.get("fun_name")))
-        if event in self._BUILD:
-            self.build_s += secs
+        with self._lock:
+            if event == self._COMPILE:
+                self.compiles += 1
+                self.names.append(str(kw.get("fun_name")))
+            if event in self._BUILD:
+                self.build_s += secs
 
     def _on_event(self, event: str, **_) -> None:
         if event == self._CACHE_HIT:
-            self.cache_loads += 1
+            with self._lock:
+                self.cache_loads += 1
 
     def reset(self) -> None:
         self.compiles, self.cache_loads, self.build_s = 0, 0, 0.0
@@ -350,7 +376,8 @@ class Run:
         common = dict(reserve_keys=self.schema.key_families(),
                       materialize=views or None,
                       certifier=cfg["certifier"],
-                      resolve_cache=cfg["resolve_cache"])
+                      resolve_cache=cfg["resolve_cache"],
+                      **facade_options(cfg))
         if self.unified:
             self.htap = SingleNodeHTAP(cfg["olap_mode"], paged=True, **common)
             self.engine = self.htap.engine

@@ -24,12 +24,32 @@ by its name in the kernel's signature.  The kernels are pure functions, so
 nothing here changes the program's state; the calls only fill JAX's
 compile caches.  A recorded call that cannot be replayed raises
 `WarmupError`, and the run stops.
+
+JAX compiles a jitted program once per device, and keys it on each array
+argument's commitment (placed by `jax.device_put` on a device, or not) and
+on the default device in force (`jax.default_device`), even where that is
+the device it would use anyway.  So a cell's chips are each warmed, and
+each recorded call is replayed in the form the program made it: a call
+that ran under a default device is replayed under each chip's; a
+committed array argument is put on each chip; an uncommitted one is
+copied to the chip uncommitted; host arrays and scalars are passed as
+recorded.  A call with no default device and no committed argument runs
+on the process's default device whatever the cell's chips, and is
+replayed once.  `exercise` serves its scratch mirror in each default-device
+setting the recorded calls show.  Calls are told apart by shapes,
+commitment and whether a default device was set, never by the device, so
+every shape seen on any chip is replayed on all of them: routing decides
+at each serve which chip meets which shape.  With more than one chip each
+chip's replays run on a thread of their own, so that their compiles
+overlap.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import inspect
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -47,11 +67,47 @@ class WarmupError(RuntimeError):
 
 def _spec(x, *, static: bool):
     """A hashable stand-in of one argument, as far as JAX's compile cache
-    tells calls apart: arrays by type, shape and dtype, static arguments
-    (passed by keyword) by value, traced scalars by type."""
+    tells calls apart on one device: arrays by type, shape, dtype and
+    commitment, static arguments (passed by keyword) by value, traced
+    scalars by type."""
     if hasattr(x, "shape") and hasattr(x, "dtype"):
-        return (type(x).__name__, tuple(x.shape), str(x.dtype))
+        return (type(x).__name__, tuple(x.shape), str(x.dtype),
+                _committed(x))
     return (type(x).__name__, x if static else None)
+
+
+def _committed(x) -> bool:
+    """A JAX array placed on its device by `jax.device_put`."""
+    return bool(getattr(x, "committed", False))
+
+
+def _on_chip(x, chip, moved: dict, *, under: bool):
+    """A JAX array argument as the program would have made it on `chip`:
+    committed there if it was committed; if not, and the call ran `under`
+    a default device, an uncommitted copy made under `chip` as the default
+    device; anything else as it is.  `moved` keeps each array's copy, so
+    an array that several calls share moves once."""
+    import jax
+    if not isinstance(x, jax.Array) or x.devices() == {chip} or \
+            not (x.committed or under):
+        return x
+    if id(x) not in moved:
+        moved[id(x)] = (jax.device_put(x, chip) if x.committed
+                        else jax.device_put(np.asarray(x)))
+    return moved[id(x)]
+
+
+def _like(recorded, value, chip):
+    """A replacement of a recorded argument, committed to `chip` where the
+    recorded one was committed."""
+    if _committed(recorded):
+        import jax
+        return jax.device_put(value, chip)
+    return value
+
+
+def chip_name(chip) -> str:
+    return f"{chip.platform}:{chip.id}"
 
 
 def _position(fn, arg: str) -> int:
@@ -76,12 +132,20 @@ def _get(args, kwargs, pos: int, name: str):
 
 
 class KernelWarmup:
-    def __init__(self) -> None:
+    def __init__(self, chips=None) -> None:
+        """`chips`: the cell's devices (default: the first local one)."""
+        import jax
         from repro.kernels.rss_scan_agg import ops
         from repro.tensorstore import materialized
         self.ops = ops
+        self.chips = list(chips) if chips is not None \
+            else jax.local_devices()[:1]
+        self.per_chip: dict = {}       # chip name -> calls replayed there
+        self.served_under: list = []   # the default devices `exercise` used
         self.flush_rows = int(materialized.FLUSH_ROWS)
-        self.calls: dict = {}          # (name, specs) -> first (args, kwargs)
+        # (name, default device set?, specs) -> first
+        # (args, kwargs, default device or None)
+        self.calls: dict = {}
         self.real: dict = {}
         self.varies: dict = {}         # kernel -> (position, name)
         self.longest = 0               # longest member array recorded
@@ -100,26 +164,43 @@ class KernelWarmup:
 
         @functools.wraps(fn)
         def record(*args, **kwargs):
-            key = (name,
+            import jax
+            ctx = jax.config.jax_default_device
+            key = (name, ctx is not None,
                    tuple(_spec(a, static=False) for i, a in enumerate(args)
                          if i != pos),
                    tuple(sorted((k, _spec(v, static=True))
                                 for k, v in kwargs.items() if k != arg)))
-            self.calls.setdefault(key, (args, kwargs))
+            self.calls.setdefault(key, (args, kwargs, ctx))
             if name in SCAN_KERNELS:
                 self.longest = max(self.longest,
                                    len(_get(args, kwargs, pos, arg)))
             return fn(*args, **kwargs)
         return record
 
+    def _settings(self) -> list:
+        """The default devices the serve path ran under, as far as the
+        calls recorded so far show: None where a call ran with no default
+        device set (or where none was recorded), and each chip where one
+        ran under a default device."""
+        shown = {ctx is not None for _a, _k, ctx in self.calls.values()}
+        return ([None] if not shown or False in shown else []) + \
+            (self.chips if True in shown else [])
+
     def exercise(self, plans, *, slots: int, page_elems: int) -> None:
         """Serve each plan once through the fused scan path of an empty
         scratch mirror, at the shapes the deployment's mirror gives it
-        (a plan's sub-store has one page per key, missing keys included)."""
+        (a plan's sub-store has one page per key, missing keys included),
+        under each default device of `_settings()`."""
+        import jax
         from repro.tensorstore import PagedMirror
-        scratch = PagedMirror(slots=slots, page_elems=page_elems)
-        for plan in plans:
-            scratch.execute_with_writers(plan, 0, need_writers=False)
+        self.served_under = self._settings()
+        for chip in self.served_under:
+            with (jax.default_device(chip) if chip is not None
+                  else contextlib.nullcontext()):
+                scratch = PagedMirror(slots=slots, page_elems=page_elems)
+                for plan in plans:
+                    scratch.execute_with_writers(plan, 0, need_writers=False)
 
     def stop(self) -> None:
         """Give the program its kernels back, unwrapped."""
@@ -134,36 +215,70 @@ class KernelWarmup:
     def replay(self) -> int:
         """Call every recorded scan kernel with each member-array length up
         to `members_to()`, and the delta fold with every buffer length a
-        view pads to; returns the number of calls made."""
+        view pads to, on each chip the call can run on (see the module's
+        note); returns the number of calls made, and keeps each chip's in
+        `per_chip`."""
+        self.stop()
+        work: dict = {c: [] for c in self.chips}
+        for (name, *_key), (args, kwargs, ctx) in self.calls.items():
+            placed = ctx is not None or any(
+                _committed(v) for v in (*args, *kwargs.values()))
+            for chip in self.chips if placed else self.chips[:1]:
+                work[chip].append((name, args, kwargs, ctx is not None,
+                                   placed))
+        self.calls.clear()
+        if len(self.chips) == 1:
+            counts = [self._replay_on(self.chips[0], work[self.chips[0]])]
+        else:
+            with ThreadPoolExecutor(len(self.chips)) as pool:
+                counts = list(pool.map(
+                    lambda c: self._replay_on(c, work[c]), self.chips))
+        self.per_chip = {chip_name(c): n for c, n in zip(self.chips, counts)}
+        return sum(counts)
+
+    def _replay_on(self, chip, recorded: list) -> int:
         import jax
+        n = 0
+        fold_tiles: set = set()
+        moved: dict = {}
+        for name, args, kwargs, under, placed in recorded:
+            with (jax.default_device(chip) if under
+                  else contextlib.nullcontext()):
+                if placed:
+                    args = [_on_chip(a, chip, moved, under=under)
+                            for a in args]
+                    kwargs = {k: _on_chip(v, chip, moved, under=under)
+                              for k, v in kwargs.items()}
+                for a, kw in self._variants(name, args, kwargs, chip,
+                                            fold_tiles):
+                    try:
+                        jax.block_until_ready(self.real[name](*a, **kw))
+                    except Exception as exc:
+                        raise WarmupError(f"{name} cannot be replayed: "
+                                          f"{exc!r:.300}") from exc
+                    n += 1
+        return n
+
+    def _variants(self, name, args, kwargs, chip, fold_tiles: set):
+        """The recorded call with its varying argument at every length the
+        window can pass: member arrays up to `members_to()` long; for each
+        tile not yet seen, a delta buffer of every padded length."""
         import jax.numpy as jnp
 
-        self.stop()
-        calls = []
-        fold_tiles = set()
-        for (name, _s, _k), (args, kwargs) in self.calls.items():
-            pos, arg = self.varies[name]
-            if name in SCAN_KERNELS:
-                for m in range(self.members_to() + 1):
-                    calls.append((name, *_replace(args, kwargs, pos, arg,
-                                                  np.zeros(m, np.int32))))
-                continue
-            tile_pos = _position(self.real[name], FOLD_TILE)
-            tile = _get(args, kwargs, tile_pos, FOLD_TILE)
-            if tuple(tile.shape) in fold_tiles:
-                continue
-            fold_tiles.add(tuple(tile.shape))
-            rows = FOLD_MIN_ROWS
-            while rows <= self.flush_rows:
-                calls.append((name, *_replace(
-                    args, kwargs, pos, arg,
-                    jnp.zeros((rows, tile.shape[1]), jnp.int32))))
-                rows *= 2
-        self.calls.clear()
-        for name, args, kwargs in calls:
-            try:
-                jax.block_until_ready(self.real[name](*args, **kwargs))
-            except Exception as exc:
-                raise WarmupError(f"{name} cannot be replayed: "
-                                  f"{exc!r:.300}") from exc
-        return len(calls)
+        pos, arg = self.varies[name]
+        recorded = _get(args, kwargs, pos, arg)
+        if name in SCAN_KERNELS:
+            for m in range(self.members_to() + 1):
+                yield _replace(args, kwargs, pos, arg,
+                               _like(recorded, np.zeros(m, np.int32), chip))
+            return
+        tile = _get(args, kwargs, _position(self.real[name], FOLD_TILE),
+                    FOLD_TILE)
+        if tuple(tile.shape) in fold_tiles:
+            return
+        fold_tiles.add(tuple(tile.shape))
+        rows = FOLD_MIN_ROWS
+        while rows <= self.flush_rows:
+            yield _replace(args, kwargs, pos, arg, _like(
+                recorded, jnp.zeros((rows, tile.shape[1]), jnp.int32), chip))
+            rows *= 2

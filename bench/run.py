@@ -8,13 +8,16 @@ and traffic mix are found by name under `bench/configs/` and
 `bench/mixes/`, and each per-layer metric's reader under `bench/layers/`.
 One run is one process: it loads the deployment, warms it up with the
 mix's own traffic from the seed, compiles every kernel shape that traffic
-can meet, runs the traffic again for two refresh or ship cadences so that
-the snapshots catch up on that pause, measures for `--seconds`, checks a
-sample of the window's served results against the plain reference
-(`bench/reference.py`), and prints one JSON object as the last line of
-stdout.  With `--trace 0` the metrics are the cell's end-to-end metrics;
-with `--trace 1` the window runs under the JAX profiler and the metrics
-are the per-layer ones, with the device's busy time and a breakdown.
+can meet on each of the cell's chips (`jax.local_devices()[:chips]`), runs
+the traffic again for two refresh or ship cadences so that the snapshots
+catch up on that pause, measures for `--seconds`, checks a sample of the
+window's served results against the plain reference (`bench/reference.py`),
+and prints one JSON object as the last line of stdout.  With `--trace 0`
+the metrics are the cell's end-to-end metrics; with `--trace 1` the window
+runs under the JAX profiler and the metrics are the per-layer ones, with
+the device's busy time (also per chip, `busy_by_chip`) and a breakdown.
+`device` gives the peak memory of the fullest chip and of each
+(`memory_peak_by_chip`).
 The numbers compared for `correct` are printed, each beside its limit, as
 the last lines of stderr and under `checks` in the result line.
 
@@ -92,10 +95,12 @@ def device_info(chips: int, *, require_tpu: bool = True) -> dict | None:
             "count": len(devs)}
 
 
-def memory_peak_bytes() -> int:
+def memory_peaks() -> dict:
+    """Each local device's peak bytes in use, by its trace plane's name."""
     import jax
-    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-               for d in jax.local_devices())
+    return {f"/device:{d.platform.upper()}:{d.id}":
+            int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in jax.local_devices()}
 
 
 def layer_metrics(bench: dict, cell: dict, li) -> dict:
@@ -131,15 +136,16 @@ def run_cell(args, *, require_tpu: bool = True, t_start: float = T_START,
     from bench import cost
     from bench.harness import (CompileClock, LayerInput, Run, checks_pass,
                                load_config)
-    from bench.kernel_warmup import KernelWarmup, WarmupError
+    from bench.kernel_warmup import KernelWarmup, WarmupError, chip_name
     from bench.trace_reduce import find_xplane, reduce_trace
 
     peaks = cost.peaks(device["kind"]) if require_tpu else {}
     cfg = load_config(cell["config"])
     clock = CompileClock()
     marks = [("start", t_start), ("jax", time.perf_counter())]
+    chips = jax.local_devices()[:cell["chips"]]
     try:
-        warm = KernelWarmup()
+        warm = KernelWarmup(chips)
     except WarmupError as exc:
         print(f"run.py: kernel warm-up failed: {exc}", file=err)
         return 1
@@ -159,7 +165,11 @@ def run_cell(args, *, require_tpu: bool = True, t_start: float = T_START,
         warm.stop()
         print(f"run.py: kernel warm-up failed: {exc}", file=err)
         return 1
-    print(f"kernel warm-up: {n_warm} calls, member arrays up to "
+    per_chip = ", ".join(f"{n} on {c}" for c, n in warm.per_chip.items())
+    under = ", ".join(chip_name(c) if c else "none" for c in warm.served_under)
+    print(f"kernel warm-up: {n_warm} calls ({per_chip}; scratch serves "
+          f"under default device {under}) in "
+          f"{time.perf_counter() - marks[-1][1]:.3f} s, member arrays up to "
           f"{warm.members_to()} long (the traffic passed up to "
           f"{warm.longest})", file=err)
     marks.append((f"{n_warm} kernel shapes", time.perf_counter()))
@@ -195,7 +205,9 @@ def run_cell(args, *, require_tpu: bool = True, t_start: float = T_START,
         print(f"served snapshots: {len(members)} plans, members above the "
               f"floor {min(members, default=0)}..{max(members, default=0)} "
               f"({len(set(members))} distinct counts)", file=err)
-        device["memory_peak_bytes"] = memory_peak_bytes()
+        peaks_by_chip = memory_peaks()
+        device["memory_peak_bytes"] = max(peaks_by_chip.values())
+        device["memory_peak_by_chip"] = peaks_by_chip
         summary = reduce_trace(find_xplane(trace_dir)) if trace_dir else None
     finally:
         if trace_dir:
@@ -206,10 +218,13 @@ def run_cell(args, *, require_tpu: bool = True, t_start: float = T_START,
         metrics = layer_metrics(bench, cell, li)
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
+        device["busy_by_chip"] = summary.busy_by_chip
         print(f"trace: {summary.n_devices} device(s), busy "
-              f"{summary.busy_s:.6f} s of {summary.window_s:.6f} s; ops "
+              f"{summary.busy_s:.6f} s of {summary.window_s:.6f} s "
+              f"(by chip {summary.busy_by_chip}); ops "
               f"{summary.top_ops(40)}; idle by phase "
-              f"{summary.idle_by_label}", file=err)
+              f"{summary.idle_by_label}; by phase and span "
+              f"{summary.idle_by_span}", file=err)
     else:
         units = {m["name"]: m["unit"] for m in bench["end_to_end"]}
         metrics = {k: {"value": v, "unit": units[k]}

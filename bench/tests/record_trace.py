@@ -5,7 +5,7 @@
 Run on one TPU chip.  Inside a `bench:window` annotation it runs the fused
 scan kernel (scalar and flat grouped) on a 64-page store between two
 `bench:flush` annotations, with an unannotated host sleep in between (an
-idle gap labelled "other"), and copies the `.xplane.pb` to
+idle gap labelled "other/-"), and copies the `.xplane.pb` to
 `<out_dir>/scan_window.xplane.pb`.  It prints the trace's planes, lines
 and distinct device op names.
 """

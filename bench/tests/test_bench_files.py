@@ -88,3 +88,35 @@ def test_a_file_that_names_another_configuration_is_refused(tmp_path):
     (tmp_path / "configs" / "a.json").write_text(json.dumps({"name": "b"}))
     with pytest.raises(ValueError):
         harness.load_config("a", root=tmp_path)
+
+
+def test_facade_options_reach_the_facade(monkeypatch):
+    """A configuration's `facade` object is passed to the facade's
+    constructor beside the options the harness sets."""
+    from repro.mvcc import htap
+    got = {}
+
+    class Facade:
+        def __init__(self, *args, **kwargs):
+            got.update(kwargs)
+            self.engine = self.primary = None
+    monkeypatch.setattr(htap, "SingleNodeHTAP", Facade)
+    monkeypatch.setattr(htap, "MultiNodeHTAP", Facade)
+    for name in ("ch_w2_unified", "ch_w2_decoupled"):
+        got.clear()
+        cfg = dict(harness.load_config(name), facade={"replica_chip": 3})
+        harness.Run(cfg, "adhoc", 1)
+        assert got["replica_chip"] == 3
+        assert got["certifier"] == cfg["certifier"]
+
+
+@pytest.mark.parametrize("key", sorted(harness.HARNESS_OPTIONS))
+def test_a_facade_option_the_harness_sets_is_refused(tmp_path, key):
+    (tmp_path / "configs").mkdir()
+    cfg = dict(harness.load_config("ch_w2_decoupled"), facade={key: 1})
+    (tmp_path / "configs" / "ch_w2_decoupled.json").write_text(
+        json.dumps(cfg))
+    with pytest.raises(ValueError, match=key):
+        harness.load_config("ch_w2_decoupled", root=tmp_path)
+    with pytest.raises(ValueError, match=key):
+        harness.Run(cfg, "adhoc", 1)

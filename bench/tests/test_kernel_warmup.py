@@ -52,6 +52,45 @@ def test_replay_reaches_twice_the_longest_member_array_seen(monkeypatch):
     assert ops.rss_scan_agg is warm.real["rss_scan_agg"]
 
 
+def test_one_chip_replays_the_calls_as_recorded(monkeypatch):
+    """With the cell's one chip, each call is replayed as the program made
+    it, in the same number: on the recorded arrays themselves, with no
+    default device set, inline."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.rss_scan_agg import ops
+    seen: list = []
+
+    def scan(data, ts, member_ts, floor=0, *, interpret=None):
+        seen.append(("scan", data, type(member_ts), len(member_ts),
+                     jax.config.jax_default_device))
+        return np.zeros(1)
+
+    def fold(acc, delta, *, interpret=None):
+        seen.append(("fold", acc, type(delta), delta.shape[0],
+                     jax.config.jax_default_device))
+        return acc
+    for name in kernel_warmup.SCAN_KERNELS:
+        monkeypatch.setattr(ops, name, scan)
+    monkeypatch.setattr(ops, kernel_warmup.FOLD_KERNEL, fold)
+    data = jnp.zeros((8, 4), jnp.int32)
+    acc = jnp.zeros((8, 128), jnp.int32)
+    warm = KernelWarmup(jax.local_devices()[:1])
+    ops.rss_scan_agg(data, jnp.zeros(8), np.zeros(5, np.int32))
+    ops.rss_delta_fold(acc, jnp.zeros((16, 128), jnp.int32))
+    seen.clear()
+    assert warm.replay() == 15 + 6
+    assert warm.per_chip == {f"{jax.local_devices()[0].platform}:"
+                             f"{jax.local_devices()[0].id}": 21}
+    scans = [c for c in seen if c[0] == "scan"]
+    assert [c[3] for c in scans] == list(range(15))
+    assert all(c[1] is data and c[2] is np.ndarray for c in scans)
+    folds = [c for c in seen if c[0] == "fold"]
+    assert [c[3] for c in folds] == [8, 16, 32, 64, 128, 256]
+    assert all(c[1] is acc and issubclass(c[2], jax.Array) for c in folds)
+    assert {c[4] for c in seen} == {None}
+
+
 def test_a_call_that_cannot_be_replayed_raises(monkeypatch):
     ops = _fake_kernels(monkeypatch, [], fail_above=6)
     warm = KernelWarmup()
@@ -98,3 +137,33 @@ def test_a_failed_warm_up_stops_the_run(tiny_cells, monkeypatch):
     rc, out, err = _run()
     assert rc == 1 and out == ""
     assert "kernel warm-up failed" in err
+
+
+def test_the_compile_clock_counts_every_compile_of_the_warm_up_threads():
+    """The warm-up compiles on a thread per chip; no count is lost."""
+    import sys
+    import threading
+
+    from bench.harness import CompileClock
+    clock = CompileClock()
+    clock.close()
+    per_thread, n_threads = 2000, 16
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def compile_events():
+            for _ in range(per_thread):
+                clock._on_dur(CompileClock._COMPILE, 0.001,
+                              fun_name="jit(rss_scan_agg)")
+                clock._on_event(CompileClock._CACHE_HIT)
+        threads = [threading.Thread(target=compile_events)
+                   for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert clock.compiles == clock.cache_loads == per_thread * n_threads
+    assert len(clock.names) == per_thread * n_threads
