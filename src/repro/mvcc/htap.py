@@ -36,6 +36,7 @@ oracle checks preserved.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence
 
@@ -274,17 +275,52 @@ class SingleNodeHTAP:
 
 
 # ---------------------------------------------------------------- multi node
+def _on_device(device):
+    """`device` as JAX's default device for the block, or nothing at all
+    when it is None."""
+    if device is None:
+        return contextlib.nullcontext()
+    import jax
+    return jax.default_device(device)
+
+
+def _replica_devices(indices: Optional[Sequence[int]],
+                     n_replicas: int) -> list:
+    """Each replica's device: `jax.local_devices()[indices[i]]`, or None
+    for every replica when `indices` is None."""
+    if indices is None:
+        return [None] * n_replicas
+    if len(indices) != n_replicas:
+        raise ValueError(f"replica_devices names {len(indices)} devices "
+                         f"for {n_replicas} replicas")
+    import jax
+    local = jax.local_devices()
+    bad = [i for i in indices
+           if not isinstance(i, int) or not 0 <= i < len(local)]
+    if bad:
+        raise ValueError(f"replica_devices {bad} out of range: "
+                         f"{len(local)} local devices")
+    return [local[i] for i in indices]
+
+
 class Replica:
     """Asynchronous log-shipping replica: applies committed writesets in LSN
     order into its own store; optionally maintains an RSSManager from the
     same stream (begin/commit/abort + deps records) and a device-resident
-    paged mirror serving batched kernel-shaped scans."""
+    paged mirror serving batched kernel-shaped scans.
+
+    With a `device`, every entry point that reaches JAX (view registration,
+    `catch_up`, plan execution, `gc_versions`) runs with that device as
+    JAX's default device: the mirror's uploads, the view tiles and the
+    kernels' operands are made there, uncommitted, so the kernels run
+    there too."""
 
     def __init__(self, *, with_rss: bool, paged: bool = False,
                  check_scans: bool = False,
                  reserve_keys: Optional[Sequence[str]] = None,
                  materialize: Optional[Sequence[Plan]] = None,
-                 resolve_cache: bool = True) -> None:
+                 resolve_cache: bool = True, device=None) -> None:
+        self.device = device
         self.store = Store()
         self.version_store: VersionStore = ChainVersionStore(self.store)
         self.applied_lsn = 0
@@ -294,7 +330,8 @@ class Replica:
         self.rss_manager = RSSManager() if with_rss else None
         self.prot = PRoTManager(self.rss_manager) if with_rss else None
         self.mirror: Optional[PagedMirror] = \
-            PagedMirror(resolve_cache=resolve_cache) if paged else None
+            PagedMirror(resolve_cache=resolve_cache,
+                        labels=self.chip_label) if paged else None
         self.paged_store: Optional[PagedVersionStore] = \
             PagedVersionStore(self.mirror) if paged else None
         if self.mirror is not None and reserve_keys:
@@ -302,55 +339,68 @@ class Replica:
         if materialize:
             assert self.mirror is not None, \
                 "materialize= needs paged=True (views live on the mirror)"
-            for p in materialize:
-                self.mirror.register_view(p)    # advance during delta ships
+            with _on_device(device):
+                for p in materialize:
+                    self.mirror.register_view(p)  # advance in delta ships
         self._si_pins: dict[int, int] = {}    # reader id -> pinned seq
         self._next_si_reader = 1
 
+    @property
+    def chip_label(self) -> dict:
+        """The `chip` label (`<platform>:<id>`) of a placed replica's spans
+        and counters; none for an unplaced one."""
+        if self.device is None:
+            return {}
+        return {"chip": f"{self.device.platform}:{self.device.id}"}
+
     def catch_up(self, primary: Engine, *, max_records: int = 0) -> int:
-        n = 0
-        # GC floor for mirror publishes: pinned PRoT snapshots (RSS) or the
-        # oldest pinned SI snapshot.  Bounded, not absolute: an SI reader
-        # that holds its snapshot across multiple ship rounds (or an RSS
-        # member version above the prefix floor) is protected only while
-        # publishers stay < K-1 versions ahead per page — the K-slot
-        # staleness bound.
-        gc_floor = self.gc_floor_seq()
-        with span("ship_replay", _SHIP_REPLAY_H, node="replica"):
-            for rec in primary.wal.tail(self.applied_lsn):
-                if max_records and n >= max_records:
-                    break
-                self.applied_lsn = rec.lsn
-                if self.rss_manager is not None:
-                    self.rss_manager.apply(rec)
+        with _on_device(self.device):
+            n = 0
+            # GC floor for mirror publishes: pinned PRoT snapshots (RSS) or
+            # the oldest pinned SI snapshot.  Bounded, not absolute: an SI
+            # reader that holds its snapshot across multiple ship rounds (or
+            # an RSS member version above the prefix floor) is protected only
+            # while publishers stay < K-1 versions ahead per page — the
+            # K-slot staleness bound.
+            gc_floor = self.gc_floor_seq()
+            with span("ship_replay", _SHIP_REPLAY_H, node="replica",
+                      **self.chip_label):
+                for rec in primary.wal.tail(self.applied_lsn):
+                    if max_records and n >= max_records:
+                        break
+                    self.applied_lsn = rec.lsn
+                    if self.rss_manager is not None:
+                        self.rss_manager.apply(rec)
+                    if self.mirror is not None:
+                        self.mirror.apply(rec, gc_floor=gc_floor)
+                    if rec.type == "commit":
+                        # the shared WAL commit clock (effective_commit_seq),
+                        # so manager/mirror/store version stamps agree and
+                        # installs stay strictly monotone even across mixed
+                        # record kinds
+                        seq = effective_commit_seq(self.applied_seq, rec.seq)
+                        for key, value in rec.writes:
+                            self.store.chain(key).install(seq, rec.txn,
+                                                         value)
+                        self.applied_seq = seq
+                    n += 1
+            if self.rss_manager is not None and n:
+                with span("rss_construct", _RSS_CONSTRUCT_H["replica"],
+                          node="replica"):
+                    snap = self.rss_manager.construct()
                 if self.mirror is not None:
-                    self.mirror.apply(rec, gc_floor=gc_floor)
-                if rec.type == "commit":
-                    # the shared WAL commit clock (effective_commit_seq),
-                    # so manager/mirror/store version stamps agree and
-                    # installs stay strictly monotone even across mixed
-                    # record kinds
-                    seq = effective_commit_seq(self.applied_seq, rec.seq)
-                    for key, value in rec.writes:
-                        self.store.chain(key).install(seq, rec.txn, value)
-                    self.applied_seq = seq
-                n += 1
-        if self.rss_manager is not None and n:
-            with span("rss_construct", _RSS_CONSTRUCT_H["replica"],
-                      node="replica"):
-                snap = self.rss_manager.construct()
-            if self.mirror is not None:
-                # views advance with the delta ship, at the snapshot the
-                # fresh construct admits
-                self.mirror.advance_views(snap)
-            # bound replica-side RSS bookkeeping by the active/pinned window
-            self.rss_manager.gc(keep_lsn=self.prot.gc_floor(),
-                                keep_seq=self.prot.gc_floor_seq())
-        elif self.mirror is not None and n:
-            self.mirror.advance_views(self.applied_seq)
-        if self.mirror is not None and n:
-            self.mirror.gc_views(self.gc_floor_seq())
-        return n
+                    # views advance with the delta ship, at the snapshot
+                    # the fresh construct admits
+                    self.mirror.advance_views(snap)
+                # bound replica-side RSS bookkeeping by the active/pinned
+                # window
+                self.rss_manager.gc(keep_lsn=self.prot.gc_floor(),
+                                    keep_seq=self.prot.gc_floor_seq())
+            elif self.mirror is not None and n:
+                self.mirror.advance_views(self.applied_seq)
+            if self.mirror is not None and n:
+                self.mirror.gc_views(self.gc_floor_seq())
+            return n
 
     # reader snapshots -------------------------------------------------------
     def si_snapshot(self) -> int:
@@ -391,7 +441,8 @@ class Replica:
     def gc_versions(self) -> int:
         """Prune replica-side chain versions below the pinned floor
         (hot_standby_feedback analogue on the replica's own store)."""
-        with span("gc_prune", GC_PRUNE_H["replica"], node="replica"):
+        with _on_device(self.device), \
+                span("gc_prune", GC_PRUNE_H["replica"], node="replica"):
             return self.store.prune(self.gc_floor_seq())
 
     def read_si(self, snapshot_seq: int, key: str) -> Any:
@@ -406,7 +457,8 @@ class Replica:
         the paged mirror, chain-walk + host `apply_plan` otherwise;
         parity-asserted against the per-key oracle under check_scans."""
         store = self.paged_store or self.version_store
-        val = store.execute(plan, snapshot)
+        with _on_device(self.device):
+            val = store.execute(plan, snapshot)
         if self.check_scans:
             if isinstance(snapshot, RssSnapshot):
                 vals = [self.version_store.read_members(k, snapshot)
@@ -438,22 +490,30 @@ class MultiNodeHTAP:
                  route_policy="freshest", max_staleness: int = 100,
                  reserve_keys: Optional[Sequence[str]] = None,
                  materialize: Optional[Sequence[Plan]] = None,
-                 certifier=None, resolve_cache: bool = True) -> None:
+                 certifier=None, resolve_cache: bool = True,
+                 replica_devices: Optional[Sequence[int]] = None) -> None:
         """`certifier` configures the PRIMARY's commit certification (see
         `repro.mvcc.certify`).  Replicas replay begin/commit/abort + deps
         WAL records, which are certifier-independent: only WHICH txns
         commit varies, never the shape of a committed txn's records — so
-        replica-side RSS construction is untouched by the choice."""
+        replica-side RSS construction is untouched by the choice.
+
+        `replica_devices` places replica i on
+        `jax.local_devices()[replica_devices[i]]` (see `Replica`); a list
+        of another length than `n_replicas`, or an index out of range, is
+        a ValueError.  None places no replica: their arrays go wherever
+        JAX's default device is."""
         assert olap_mode in ("ssi+si", "ssi+rss")
         assert n_replicas >= 1
+        devices = _replica_devices(replica_devices, n_replicas)
         self.olap_mode = olap_mode
         self.primary = Engine("ssi", certifier=certifier)
         replicas = [Replica(with_rss=(olap_mode == "ssi+rss"),
                             paged=paged_olap, check_scans=check_scans,
                             reserve_keys=reserve_keys,
                             materialize=materialize,
-                            resolve_cache=resolve_cache)
-                    for _ in range(n_replicas)]
+                            resolve_cache=resolve_cache, device=device)
+                    for device in devices]
         self.cluster = ReplicaCluster(self.primary, replicas,
                                       policy=route_policy,
                                       max_lag=max_staleness)
@@ -504,7 +564,7 @@ class MultiNodeHTAP:
         hist = _serve_hist(self._serve_h, (kind, idx), facade="multi",
                            plan=kind, replica=idx)
         with span("olap_serve", hist, facade="multi", plan=kind,
-                  replica=idx):
+                  replica=idx, **self.cluster.replicas[idx].chip_label):
             return self.cluster.execute(snap, plan)
 
     def olap_execute_batch(self, entries: Sequence[tuple]) -> list[Any]:
@@ -534,7 +594,8 @@ class MultiNodeHTAP:
         hist = _serve_hist(self._serve_h, ("BatchPlan", idx), facade="multi",
                            plan="BatchPlan", replica=idx)
         with span("olap_serve", hist, facade="multi", plan="BatchPlan",
-                  replica=idx, fused=len(entries)):
+                  replica=idx, fused=len(entries),
+                  **self.cluster.replicas[idx].chip_label):
             return list(self.cluster.execute(entries[0][0], batch))
 
     def olap_release(self, snap) -> None:

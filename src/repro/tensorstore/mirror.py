@@ -171,7 +171,10 @@ def decode_value(row: np.ndarray) -> Any:
 
 class PagedMirror:
     def __init__(self, *, slots: int = 8, page_elems: int = 32,
-                 capacity: int = 64, resolve_cache: bool = True) -> None:
+                 capacity: int = 64, resolve_cache: bool = True,
+                 labels: dict | None = None) -> None:
+        """`labels` are added to every series of this mirror (a placed
+        replica's `chip`)."""
         assert page_elems >= 3
         self.slots = slots
         self.page_elems = page_elems
@@ -189,7 +192,7 @@ class PagedMirror:
         # range: dense-range fast-path hits for fused plan executions — a
         # contiguous ascending page run slices the store (no gather);
         # `reserve` key families contiguously to raise the hit rate.
-        lbl = {"mirror": REGISTRY.scope("mirror")}
+        lbl = {"mirror": REGISTRY.scope("mirror"), **(labels or {})}
         self.range_stats = StatsView(REGISTRY, "mirror_range",
                                      ("dense", "gather"), labels=lbl)
         # bytes of every host array a serve or view path copies to the
