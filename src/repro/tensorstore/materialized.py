@@ -272,10 +272,11 @@ class MaterializedView:
         for lane in lanes:
             field, tag_main, tag_alt, thr = self.lane_params[lane]
             pages = self.mirror.page_index(self.lane_groups[lane])
+            store, maxabs = self.mirror.jnp_store_for(pages)
             raw = snapshot_agg_members(
-                self.mirror.jnp_store_for(pages), members, floor,
-                tag_main=tag_main, tag_alt=tag_alt, threshold=thr,
-                use_kernel=self.use_kernel, interpret=self.interpret)
+                store, members, floor, tag_main=tag_main, tag_alt=tag_alt,
+                threshold=thr, maxabs=maxabs, use_kernel=self.use_kernel,
+                interpret=self.interpret)
             self.shadow[lane, 3], self.shadow[lane, 4] = raw[3], raw[4]
             self.acc = self.acc.at[lane, 3].set(jnp.int32(raw[3])) \
                                .at[lane, 4].set(jnp.int32(raw[4]))

@@ -51,8 +51,8 @@ _FINALIZE_H = REGISTRY.histogram("olap_stage_seconds", stage="finalize")
 # `<span>_seconds` family each.  serve_upload is the host gather and the
 # copy to the device of a scanned sub-store (a `_store_for` miss);
 # serve_kernel runs from a kernel launch to its folded result (it holds
-# `field_maxabs`, the `serve_maxabs` span of the kernel ops); serve_host_agg
-# is the host-mode decode and aggregate.
+# the overflow guard's bound lookup, the `serve_maxabs` span of the kernel
+# ops); serve_host_agg is the host-mode decode and aggregate.
 _UPLOAD_H = REGISTRY.histogram("serve_upload_seconds")
 _KERNEL_H = REGISTRY.histogram("serve_kernel_seconds")
 _HOST_AGG_H = REGISTRY.histogram("serve_host_agg_seconds")
@@ -181,6 +181,10 @@ class PagedMirror:
         self.data = np.zeros((capacity, slots, page_elems), np.int32)
         self.ts = np.zeros((capacity, slots), np.int32)
         self.writer = np.zeros((capacity, slots), np.int32)  # txn per slot
+        # exact largest |payload element 1| over each page's slots, kept
+        # by `_publish`: the int32 overflow guard's bound for any page set
+        # (`jnp_store_for`), with no copy of the device store back
+        self.page_maxabs = np.zeros(capacity, np.int64)
         self.page_of: dict[str, int] = {}
         self.keys: list[str] = []
         self.applied_lsn = 0
@@ -298,6 +302,8 @@ class PagedMirror:
             self.ts = np.concatenate([self.ts, np.zeros_like(self.ts)])
             self.writer = np.concatenate([self.writer,
                                           np.zeros_like(self.writer)])
+            self.page_maxabs = np.concatenate(
+                [self.page_maxabs, np.zeros_like(self.page_maxabs)])
         self.page_of[key] = page
         self.keys.append(key)
         self._page_gen += 1        # page-index entries holding a -1 for
@@ -321,16 +327,25 @@ class PagedMirror:
                  gc_floor: int) -> None:
         """numpy twin of `paged.publish_page`: recycle the oldest slot, but
         never the newest slot at-or-below gc_floor (a pinned reader may still
-        resolve to it)."""
+        resolve to it).  Keeps `page_maxabs[page]` exact: it rises with
+        the new value, and is recomputed over the page's slots only when
+        the recycled slot held it."""
         row = self.ts[page]
         masked = np.where(row <= gc_floor, row, -1)
         protected = int(masked.argmax())
         order = row.astype(np.int64).copy()
         order[protected] = np.iinfo(np.int64).max
         victim = int(order.argmin())
+        old = abs(self.data.item(page, victim, 1))
         self.data[page, victim] = payload
         self.ts[page, victim] = seq
         self.writer[page, victim] = writer
+        new, bound = abs(payload.item(1)), self.page_maxabs.item(page)
+        if new >= bound:
+            self.page_maxabs[page] = new
+        elif old == bound:
+            self.page_maxabs[page] = np.abs(
+                self.data[page, :, 1].astype(np.int64)).max()
 
     # --------------------------------------------------------------- replay
     def apply(self, rec: WalRecord, *, gc_floor: int = 0) -> bool:
@@ -648,27 +663,27 @@ class PagedMirror:
                                       self._page_gen)
         return pages
 
-    def _store_for(self, keys, pages: np.ndarray) -> dict:
+    def _store_for(self, keys, pages: np.ndarray) -> tuple[dict, int]:
         """`jnp_store_for` behind the horizon-keyed store cache: the
-        gathered `{'data','ts'}` device buffers for a plan's key sequence,
-        reused until a publish changes page content (`apply` clears the
-        cache).  The cached dense/gather verdict re-counts into
-        `range_stats` on hits, so the fast-path hit RATE keeps meaning
-        'per fused plan execution' with the cache on."""
+        gathered `{'data','ts'}` device buffers for a plan's key sequence
+        and their field bound, reused until a publish changes page content
+        (`apply` clears the cache).  The cached dense/gather verdict
+        re-counts into `range_stats` on hits, so the fast-path hit RATE
+        keeps meaning 'per fused plan execution' with the cache on."""
         if not self.resolve_cache:
             return self.jnp_store_for(pages)
         keys_t = keys if isinstance(keys, tuple) else tuple(keys)
         ent = self._store_cache.get(keys_t)
         if ent is not None:
-            store, verdict = ent
+            store, maxabs, verdict = ent
             self.range_stats[verdict] += 1
             self.cache_stats["store_hits"] += 1
-            return store
+            return store, maxabs
         self.cache_stats["store_misses"] += 1
-        store = self.jnp_store_for(pages)
+        store, maxabs = self.jnp_store_for(pages)
         self._cap(self._store_cache, self._STORE_CAP)
-        self._store_cache[keys_t] = (store, self._last_range_verdict)
-        return store
+        self._store_cache[keys_t] = (store, maxabs, self._last_range_verdict)
+        return store, maxabs
 
     def _lane_layout_for(self, plans) -> tuple[list, list, dict]:
         """`_lane_layout` memoized per plan tuple (frozen dataclasses hash
@@ -696,12 +711,14 @@ class PagedMirror:
         return (lambda ts: np.where(ts <= wm, ts, -1),
                 np.zeros((0,), np.int32), wm)
 
-    def jnp_store_for(self, pages: np.ndarray) -> dict:
+    def jnp_store_for(self, pages: np.ndarray) -> tuple[dict, int]:
         """Columnar multi-page gather: the `{'data','ts'}` sub-store for a
         resolved page-index array, device-shaped for the fused scan
-        kernels.  Missing keys (-1) become initial pages (ts == 0, decode
-        to 0); sublane-padding pages are tagged TAG_PAD so fused aggregates
-        never count them.  A contiguous ascending page range
+        kernels, and its largest |field| (the overflow guard's `maxabs`,
+        from `page_maxabs` over the same pages).  Missing keys (-1) become
+        initial pages (ts == 0, decode to 0); sublane-padding pages are
+        tagged TAG_PAD so fused aggregates never count them; both add 0 to
+        the bound.  A contiguous ascending page range
         (`paged.as_page_range`) skips the gather entirely (pure slice —
         the dense key-range fast path)."""
         import jax.numpy as jnp
@@ -717,13 +734,16 @@ class PagedMirror:
                   verdict=self._last_range_verdict):
             if rng is not None:
                 data, ts = self.data[rng[0]:rng[1]], self.ts[rng[0]:rng[1]]
+                bounds = self.page_maxabs[rng[0]:rng[1]]
             else:
                 safe = np.where(pages >= 0, pages, 0)
                 data, ts = self.data[safe], self.ts[safe]
+                bounds = self.page_maxabs[safe]
                 miss = pages < 0
                 if miss.any():
                     data[miss] = 0
                     ts[miss] = 0
+                    bounds[miss] = 0
             if pad:
                 pd = np.zeros((pad,) + self.data.shape[1:], np.int32)
                 pd[:, :, 0] = TAG_PAD
@@ -731,7 +751,8 @@ class PagedMirror:
                 ts = np.concatenate(
                     [ts, np.zeros((pad,) + self.ts.shape[1:], np.int32)])
             self.h2d_bytes.inc(data.nbytes + ts.nbytes)
-            return {"data": jnp.asarray(data), "ts": jnp.asarray(ts)}
+            maxabs = int(bounds.max()) if bounds.size else 0
+            return {"data": jnp.asarray(data), "ts": jnp.asarray(ts)}, maxabs
 
     def _scalar_raws(self, pages: np.ndarray, member_ts, floor, ops, *,
                      keys: Sequence[str] | None = None,
@@ -748,7 +769,7 @@ class PagedMirror:
             return {cfg: list(empty) for cfg in configs}
         from ..kernels.rss_scan_agg.ops import snapshot_agg_members
 
-        store = self.jnp_store_for(pages) if keys is None \
+        store, maxabs = self.jnp_store_for(pages) if keys is None \
             else self._store_for(keys, pages)
         mem = np.asarray(member_ts, np.int32)
         raws = {}
@@ -758,7 +779,7 @@ class PagedMirror:
                 self.h2d_bytes.inc(mem.nbytes)     # the member array
                 raws[(field, thr)] = snapshot_agg_members(
                     store, mem, floor, tag_main=tag_main, tag_alt=tag_alt,
-                    threshold=thr, use_kernel=use_kernel,
+                    threshold=thr, maxabs=maxabs, use_kernel=use_kernel,
                     interpret=interpret)
         return raws
 
@@ -808,7 +829,7 @@ class PagedMirror:
                 return rows
         flat_keys = tuple(flat_keys)
         pages = self.page_index(flat_keys)
-        store = self._store_for(flat_keys, pages)
+        store, maxabs = self._store_for(flat_keys, pages)
         gid = np.full(int(store["ts"].shape[0]), -1, np.int32)
         gid[:len(pages)] = np.concatenate(
             [np.full(len(grp), g, np.int32)
@@ -822,7 +843,7 @@ class PagedMirror:
             rows, used = kops.grouped_agg_auto(
                 store, gid, len(lane_groups), mem, floor,
                 group_params=gparams, n_plans=n_plans, mode=mode,
-                use_kernel=use_kernel, interpret=interpret)
+                maxabs=maxabs, use_kernel=use_kernel, interpret=interpret)
             TRACER.annotate(mode=used)
         self.exec_stats["mode_" + used] += 1
         return rows
@@ -952,3 +973,9 @@ class PagedMirror:
                             np.zeros((pad,) + self.ts.shape[1:], np.int32)])
         self.h2d_bytes.inc(data.nbytes + ts.nbytes)
         return {"data": jnp.asarray(data), "ts": jnp.asarray(ts)}
+
+    def maxabs(self) -> int:
+        """Largest |field| of the store `jnp_store()` exports: its
+        overflow-guard bound (unwritten and padding pages add 0)."""
+        return int(self.page_maxabs[:self.n_pages].max()) \
+            if self.n_pages else 0

@@ -538,9 +538,9 @@ def test_h2d_bytes_count_an_upload_and_not_a_store_cache_hit():
     keys = ("a", "b")
     pages = mirror.page_index(keys)
     up0 = REGISTRY.hist_summary("serve_upload_seconds")["count"]
-    store = mirror._store_for(keys, pages)
+    store, _ = mirror._store_for(keys, pages)
     sent = store["data"].nbytes + store["ts"].nbytes
     assert mirror.h2d_bytes.value == sent > 0
-    assert mirror._store_for(keys, pages) is store       # a cache hit
+    assert mirror._store_for(keys, pages)[0] is store    # a cache hit
     assert mirror.h2d_bytes.value == sent
     assert REGISTRY.hist_summary("serve_upload_seconds")["count"] == up0 + 1

@@ -307,6 +307,7 @@ class TestOverflowGuards:
         assert kops.LAUNCH_STATS["overflow_fallbacks"] == fallbacks
         assert kops.LAUNCH_STATS["flat"] == 1          # demoted
         assert kops.LAUNCH_STATS["pallas_calls"] == pallas
+        assert kops.LAUNCH_STATS["maxabs_device_reads"] == 0
         assert got == ChainVersionStore(eng.store).execute(plan, eng.seq)
 
     def test_scalar_agg_past_block_bound_is_exact(self):
@@ -324,5 +325,42 @@ class TestOverflowGuards:
         got = paged.execute(plan, eng.seq)
         assert kops.LAUNCH_STATS["overflow_fallbacks"] == 1
         assert kops.LAUNCH_STATS["pallas_calls"] == 0
+        assert kops.LAUNCH_STATS["maxabs_device_reads"] == 0
         assert got == ChainVersionStore(eng.store).execute(plan, eng.seq)
         assert got == 16 * 2**30 + sum(range(16))
+
+    def test_block_bound_clears_once_large_value_is_recycled(self):
+        """The guard's bound is the mirror's per-page bound, which falls
+        when a page's K slots recycle a large value out: a key at 2**30
+        takes the per-page fallback; after 8 small overwrites it takes
+        the kernel, exactly as a device read of the same store decides."""
+        eng = Engine("ssi")
+        t = eng.begin()
+        eng.write(t, "k:0", 2**30)
+        for i in range(1, 16):
+            eng.write(t, f"k:{i}", i)
+        eng.commit(t)
+        mirror = _mirror_for(eng)
+        paged = PagedVersionStore(mirror)
+        plan = AggPlan(tuple(f"k:{i}" for i in range(16)),
+                       AggOp("sum", "int"))
+        keys = plan_keys(plan)
+
+        def device_verdict():
+            store, _ = mirror._store_for(keys, mirror.page_index(keys))
+            return kops.safe_block_pages(kops.field_maxabs(store))
+
+        for small, fallbacks, pallas in ((None, 1, 0), (3, 0, 1)):
+            if small is not None:
+                for _ in range(8):
+                    t = eng.begin()
+                    eng.write(t, "k:0", small)
+                    eng.commit(t)
+                mirror.catch_up(eng.wal)
+            kops.reset_launch_stats()
+            got = paged.execute(plan, eng.seq)
+            assert kops.LAUNCH_STATS["overflow_fallbacks"] == fallbacks
+            assert kops.LAUNCH_STATS["pallas_calls"] == pallas
+            assert kops.LAUNCH_STATS["maxabs_device_reads"] == 0
+            assert (device_verdict() is None) == bool(fallbacks)
+            assert got == ChainVersionStore(eng.store).execute(plan, eng.seq)

@@ -119,7 +119,7 @@ with jax.transfer_guard_device_to_device("disallow"):
             if rnd == 2:
                 rep = placed.cluster.replicas[hp[1]]
                 out.setdefault("stores", {})[hp[1]] = sorted(
-                    {str(d) for store, _v in rep.mirror._store_cache.values()
+                    {str(d) for store, *_ in rep.mirror._store_cache.values()
                      for arr in store.values() for d in arr.devices()})
             placed.olap_release(hp)
             unplaced.olap_release(hu)
