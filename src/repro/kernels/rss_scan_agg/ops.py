@@ -19,8 +19,8 @@ them exactly on host.  The chunked path folds ON DEVICE, so it needs the
 whole-scan bound (|field| max * P < 2**31) and falls back to flat-lane
 when violated.  Both fallbacks count `overflow_fallbacks`.  The |field|
 max comes from the caller (`maxabs=`: the paged mirror keeps it per page
-as it writes); only a store with none given is copied back from the
-device to find it, counted in `maxabs_device_reads`.
+as it writes); `field_maxabs` reads the same number back from a device
+store.
 `LAUNCH_STATS` counts dispatches, pallas calls, chosen modes and
 fallbacks — the driver and verify.sh read it to assert
 one-launch-per-fused-batch.
@@ -75,8 +75,7 @@ FLAT_MODE_MAX_GROUPS = 32
 # layer's metrics; dict-shaped API preserved for existing readers
 LAUNCH_STATS = StatsView(REGISTRY, "kernel_launch",
                          ("dispatches", "pallas_calls", "host", "flat",
-                          "chunked", "overflow_fallbacks", "delta_folds",
-                          "maxabs_device_reads"))
+                          "chunked", "overflow_fallbacks", "delta_folds"))
 
 
 def reset_launch_stats() -> dict:
@@ -105,27 +104,23 @@ def select_grouped_mode(n_pages: int, n_groups: int, n_plans: int = 1, *,
 
 # --- overflow guard ---------------------------------------------------------
 
-# the overflow guard's bound lookup, once per call: the caller's bound, or
-# else the device read of `field_maxabs`
+# the overflow guard's bound lookup, once per call
 _MAXABS_H = REGISTRY.histogram("serve_maxabs_seconds")
 
 
 def field_maxabs(store: dict) -> int:
     """Largest |aggregable field| (payload element 1) across every slot of
-    the store, by a device-to-host copy of the whole store."""
+    the store, by a device-to-host copy of the whole store: the reference
+    that a caller's `maxabs` must equal."""
     col = np.asarray(store["data"])[:, :, 1]
     return int(np.abs(col.astype(np.int64)).max()) if col.size else 0
 
 
-def guard_maxabs(store: dict, maxabs: Optional[int] = None) -> int:
-    """The host-side input to the int32 partial bounds: `maxabs` when the
-    caller gives it (it must equal `field_maxabs(store)`), else the device
-    read, counted in `maxabs_device_reads`."""
+def guard_maxabs(maxabs: int) -> int:
+    """The host-side input to the int32 partial bounds: the caller's
+    `maxabs`, which must equal `field_maxabs` of the scanned store."""
     with span("serve_maxabs", _MAXABS_H):
-        if maxabs is not None:
-            return int(maxabs)
-        LAUNCH_STATS["maxabs_device_reads"] += 1
-        return field_maxabs(store)
+        return int(maxabs)
 
 
 def safe_block_pages(maxabs: int) -> Optional[int]:
@@ -162,7 +157,7 @@ def fold_partials(partials) -> list[int]:
 def snapshot_agg_members(store: dict, member_ts, floor=0, *,
                          tag_main: int, tag_alt: int = -2,
                          threshold: Optional[int] = None,
-                         maxabs: Optional[int] = None,
+                         maxabs: int,
                          use_kernel: bool = True,
                          interpret: Optional[bool] = None) -> list[int]:
     """Fused RSS membership scan + aggregate over a paged store
@@ -179,10 +174,10 @@ def snapshot_agg_members(store: dict, member_ts, floor=0, *,
     (min/max carry sentinels when count == 0).  When the store's field
     magnitude could wrap an 8-page block partial, the jnp reference
     computes per-page partials instead (counted in
-    `overflow_fallbacks`); `maxabs` is that magnitude where the caller
-    knows it (`guard_maxabs`)."""
+    `overflow_fallbacks`); `maxabs` is that magnitude, given by the caller
+    (`guard_maxabs`)."""
     thresh = _I32_MAX if threshold is None else int(threshold)
-    bp = safe_block_pages(guard_maxabs(store, maxabs))
+    bp = safe_block_pages(guard_maxabs(maxabs))
     if bp is None:
         LAUNCH_STATS["overflow_fallbacks"] += 1
     if bp is None or not use_kernel:
@@ -221,7 +216,7 @@ def snapshot_group_agg_members(store: dict, gid, n_groups: int,
                                tag_main: int = 1, tag_alt: int = -2,
                                threshold: Optional[int] = None,
                                group_params=None,
-                               maxabs: Optional[int] = None,
+                               maxabs: int,
                                use_kernel: bool = True,
                                interpret: Optional[bool] = None) \
         -> list[list[int]]:
@@ -241,7 +236,7 @@ def snapshot_group_agg_members(store: dict, gid, n_groups: int,
     to 0).  Same overflow fallback as `snapshot_agg_members`."""
     thresh = _I32_MAX if threshold is None else int(threshold)
     gid = jnp.asarray(np.asarray(gid, np.int32).reshape(-1, 1))
-    bp = safe_block_pages(guard_maxabs(store, maxabs))
+    bp = safe_block_pages(guard_maxabs(maxabs))
     if bp is None:
         LAUNCH_STATS["overflow_fallbacks"] += 1
     if group_params is not None:
@@ -323,7 +318,7 @@ def delta_fold(acc, delta, *, use_kernel: bool = True,
 def grouped_agg_auto(store: dict, gid, n_groups: int, member_ts, floor=0,
                      *, group_params=None, n_plans: int = 1,
                      mode: Optional[str] = None,
-                     maxabs: Optional[int] = None,
+                     maxabs: int,
                      use_kernel: bool = True,
                      interpret: Optional[bool] = None):
     """Shape-dispatched grouped aggregate: pick flat / chunked by
@@ -337,8 +332,7 @@ def grouped_agg_auto(store: dict, gid, n_groups: int, member_ts, floor=0,
     P = int(store["ts"].shape[0])
     m = select_grouped_mode(P, n_groups, n_plans, override=mode)
     if m == "chunked":
-        maxabs = guard_maxabs(store, maxabs)
-        if not scan_bound_ok(maxabs, P):
+        if not scan_bound_ok(guard_maxabs(maxabs), P):
             LAUNCH_STATS["overflow_fallbacks"] += 1
             m = "flat"
     LAUNCH_STATS["dispatches"] += 1

@@ -973,9 +973,3 @@ class PagedMirror:
                             np.zeros((pad,) + self.ts.shape[1:], np.int32)])
         self.h2d_bytes.inc(data.nbytes + ts.nbytes)
         return {"data": jnp.asarray(data), "ts": jnp.asarray(ts)}
-
-    def maxabs(self) -> int:
-        """Largest |field| of the store `jnp_store()` exports: its
-        overflow-guard bound (unwritten and padding pages add 0)."""
-        return int(self.page_maxabs[:self.n_pages].max()) \
-            if self.n_pages else 0

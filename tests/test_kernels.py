@@ -260,13 +260,15 @@ class TestInterpretResolution:
         gid = rng.integers(-1, G, (P, 1)).astype(np.int32)
         args = (store["data"], store["ts"])
         if op == "scalar":
-            out = kops.snapshot_agg_members(store, mem, 5, tag_main=1,
-                                            tag_alt=0, interpret=None)
+            out = kops.snapshot_agg_members(
+                store, mem, 5, tag_main=1, tag_alt=0,
+                maxabs=kops.field_maxabs(store), interpret=None)
             ref = kops.fold_partials(
                 rss_scan_agg_ref(*args, mem, 5, 1, 0))
         elif op == "flat":
-            out = kops.snapshot_group_agg_members(store, gid, G, mem, 5,
-                                                  interpret=None)
+            out = kops.snapshot_group_agg_members(
+                store, gid, G, mem, 5, maxabs=kops.field_maxabs(store),
+                interpret=None)
             ref = kops.fold_group_partials(rss_scan_agg_grouped_ref(
                 *args, jnp.asarray(gid), mem, 5, n_groups=G))
         elif op == "chunked":

@@ -35,6 +35,12 @@ def _check(store, maxabs):
     assert maxabs == kops.field_maxabs(store)
 
 
+def _export_bound(mirror) -> int:
+    """The bound of `jnp_store()`'s export: its written pages' bounds
+    (padding pages add 0)."""
+    return int(mirror.page_maxabs[:mirror.n_pages].max(initial=0))
+
+
 def _value(rng):
     r = rng.random()
     if r < 0.15:
@@ -89,7 +95,7 @@ def test_bound_equals_device_read(seed, pin):
             store, maxabs = mirror.jnp_store_for(mirror.page_index(keys))
             _check(store, maxabs)
             verdicts.add(mirror._last_range_verdict)
-        _check(mirror.jnp_store(), mirror.maxabs())
+        _check(mirror.jnp_store(), _export_bound(mirror))
     assert verdicts == {"dense", "gather"}
     assert mirror.data.shape[0] > 4
     assert falls                # large values were recycled out of pages
@@ -144,12 +150,14 @@ def test_store_cache_hit_keeps_bound_until_apply():
 
 def test_empty_mirror_exports_zero_bound():
     mirror = PagedMirror()
-    _check(mirror.jnp_store(), mirror.maxabs())
+    _check(mirror.jnp_store(), _export_bound(mirror))
     _check(*mirror.jnp_store_for(mirror.page_index(())))
-    assert mirror.maxabs() == 0
+    assert _export_bound(mirror) == 0
 
 
-def test_only_an_unbounded_call_reads_the_device():
+def test_the_ops_require_the_bound():
+    """No op reads the bound back from the device: a call without one
+    is an error, and a call with the mirror's bound aggregates exactly."""
     mirror = PagedMirror()
     mirror.apply(WalRecord(lsn=1, type="commit", txn=1,
                            writes=tuple((f"k:{i}", i) for i in range(8)),
@@ -157,20 +165,23 @@ def test_only_an_unbounded_call_reads_the_device():
     store, maxabs = mirror.jnp_store_for(mirror.page_index(
         tuple(f"k:{i}" for i in range(8))))
     mem = np.zeros((0,), np.int32)
-    kops.reset_launch_stats()
-    bounded = kops.snapshot_agg_members(store, mem, 1, tag_main=1,
-                                        maxabs=maxabs, use_kernel=False)
-    assert kops.LAUNCH_STATS["maxabs_device_reads"] == 0
-    read = kops.snapshot_agg_members(store, mem, 1, tag_main=1,
-                                     use_kernel=False)
-    assert kops.LAUNCH_STATS["maxabs_device_reads"] == 1
-    assert bounded == read
-    assert bounded[:2] == [sum(range(8)), 8]
+    gid = np.zeros(int(store["ts"].shape[0]), np.int32)
+    for op, args in ((kops.snapshot_agg_members, ()),
+                     (kops.snapshot_group_agg_members, (gid, 1)),
+                     (kops.grouped_agg_auto, (gid, 1))):
+        with pytest.raises(TypeError, match="maxabs"):
+            op(store, *args, mem, 1, **({"tag_main": 1}
+                                        if not args else {}))
+    got = kops.snapshot_agg_members(store, mem, 1, tag_main=1,
+                                    maxabs=maxabs, use_kernel=False)
+    assert got[:2] == [sum(range(8)), 8]
 
 
 # ------------------------------------------------------- facade threading
 _GUARD_STATS = ("dispatches", "pallas_calls", "host", "flat", "chunked",
-                "overflow_fallbacks", "maxabs_device_reads")
+                "overflow_fallbacks")
+_GUARDED_OPS = ("snapshot_agg_members", "snapshot_group_agg_members",
+                "grouped_agg_auto")
 
 
 @pytest.mark.parametrize("mode", [None, "chunked"])
@@ -179,9 +190,9 @@ def test_facade_bound_matches_forced_device_read(facade, mode, monkeypatch):
     """Both HTAP facades serve ad hoc plans and registered dashboard views
     (with min/max demotions) twice on one seed: once on the mirror's bound,
     whose every use is checked against the device read, and once with the
-    device read forced.  `check_scans` holds each serve to the per-key
-    engine oracle; the two runs must choose the same modes, fall back as
-    often, and give the same outputs, and only the forced one reads."""
+    device read forced in its place.  `check_scans` holds each serve to the
+    per-key engine oracle; the two runs must choose the same modes, fall
+    back as often, and give the same outputs."""
     from repro.mvcc.driver import run_multi_node, run_single_node
     from repro.obs import REGISTRY
 
@@ -189,19 +200,21 @@ def test_facade_bound_matches_forced_device_read(facade, mode, monkeypatch):
         monkeypatch.setenv(kops.GROUPED_MODE_ENV, mode)
     run, extra = ((run_single_node, {}) if facade == "single"
                   else (run_multi_node, {"n_replicas": 2}))
-    guard = kops.guard_maxabs
+    real = {name: getattr(kops, name) for name in _GUARDED_OPS}
+    checks = []
 
-    def checked(store, maxabs=None):
-        assert maxabs is not None             # every mirror path has one
-        assert maxabs == kops.field_maxabs(store)
-        return guard(store, maxabs)
-
-    def forced(store, maxabs=None):
-        return guard(store)
+    def guarded(name, forced):
+        def call(store, *args, maxabs, **kwargs):
+            device = kops.field_maxabs(store)
+            checks.append(maxabs == device)
+            return real[name](store, *args, maxabs=device if forced
+                              else maxabs, **kwargs)
+        return call
 
     runs = []
-    for fake in (checked, forced):
-        monkeypatch.setattr(kops, "guard_maxabs", fake)
+    for forced in (False, True):
+        for name in _GUARDED_OPS:
+            monkeypatch.setattr(kops, name, guarded(name, forced))
         m = run(olap_mode="ssi+rss", oltp_clients=3, olap_clients=2,
                 rounds=600, olap_scan=True, paged_olap=True,
                 check_scans=True, materialize=True, batch_plans=True,
@@ -209,9 +222,7 @@ def test_facade_bound_matches_forced_device_read(facade, mode, monkeypatch):
         runs.append((m, {k: REGISTRY.total(f"kernel_launch_{k}")
                          for k in _GUARD_STATS}))
     (m, stats), (m_dev, stats_dev) = runs
-    assert stats["maxabs_device_reads"] == 0
-    assert stats_dev.pop("maxabs_device_reads") > 0
-    stats.pop("maxabs_device_reads")
+    assert checks and all(checks)
     assert stats == stats_dev
     assert m.olap_view_demotions > 0 and m.olap_view_hits > 0
     assert (m.olap_mode_flat, m.olap_mode_chunked, m.olap_mode_host) == \
