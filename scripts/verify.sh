@@ -1,9 +1,10 @@
 #!/usr/bin/env bash
 # Repo verification: tier-1 tests + interpret-mode kernel parity checks.
 #
-#   bash scripts/verify.sh          # tier-1 + kernel parity (fast-ish)
-#   bash scripts/verify.sh --bench  # also run the full benchmark suite
-#                                   # (writes BENCH_kernels.json)
+#   bash scripts/verify.sh          # tier-1 + kernel parity + examples
+#
+# Speed is measured on the chip by bench/run.py (see BENCHMARK.json), not
+# here: every timing this script could take comes from the CPU.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
@@ -317,27 +318,11 @@ EOF
 
 echo
 echo "== examples (smoke mode: demos must not rot) =="
-for ex in quickstart anomaly_demo paged_snapshot_reads cluster_fanout \
+for ex in anomaly_demo paged_snapshot_reads cluster_fanout \
           observability_demo; do
     python "examples/$ex.py" > /dev/null
     echo "example OK: $ex"
 done
-python examples/htap_train_serve.py --smoke > /dev/null
-echo "example OK: htap_train_serve (--smoke)"
-
-echo
-echo "== benchmark entry points (--smoke: tiny scale, no BENCH_kernels.json) =="
-python -m benchmarks.run --smoke > /dev/null
-echo "bench smoke OK (all entry points, incl. scan-vs-fused-agg sweep)"
-
-if [[ "${1:-}" == "--bench" ]]; then
-    echo
-    echo "== benchmarks (writes BENCH_kernels.json) =="
-    python -m benchmarks.run
-    echo
-    echo "== perf regression gate (fresh run vs committed baseline) =="
-    python -m benchmarks.check_regression
-fi
 
 echo
 echo "verify: all green"

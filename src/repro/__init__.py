@@ -1,17 +1,13 @@
 """repro: Serializable HTAP with Abort-/Wait-free Snapshot Read (RSS),
-reproduced as a multi-pod JAX training/serving framework.
+reproduced with OLTP on the host and OLAP in Pallas kernels on the TPU.
 
 Subpackages:
   core        the paper's contribution (RSS theory, Algorithm 1, SSI, WAL)
   mvcc        executable MVCC engine + HTAP architectures + CH-benchmark
   cluster     N-way WAL fan-out replica cluster + lag-aware RSS routing
-  tensorstore versioned parameter/page stores (SI-V snapshot reads)
-  models      the 10 assigned architectures, config-driven
-  configs     architecture registry (get_config / list_archs)
+  tensorstore paged mirror, plan IR and materialized views over it
   kernels     Pallas TPU kernels + jnp oracles
-  train/serve training loop (fault-tolerant) and RSS-pinned serving
-  optim/data/checkpoint  substrates
-  launch      meshes, shardings, dry-run, CLI launchers
+  obs         metric registry and profiler-visible spans
 """
 
 __version__ = "1.0.0"

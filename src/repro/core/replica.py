@@ -232,7 +232,7 @@ class RSSManager:
 
     def construct_batch(self) -> RssSnapshot:
         """The pre-incremental O(history) construction path, kept as the
-        cost baseline for `benchmarks.bench_freshness` and as an oracle.
+        oracle the incremental path is tested against.
         Requires an un-GC'd manager (full begin/end bookkeeping)."""
         act = self.active()
         horizon = min((self.begun[t] for t in act), default=_INF)

@@ -6,7 +6,7 @@ active (then the read-only transaction can never be part of a dangerous
 structure, so SSI validation can be skipped entirely).
 
 This module provides the prefix-level predicate and the reader-wait oracle
-used by the `mvcc` engine's SSI+SafeSnapshots mode and by benchmarks to
+used by the `mvcc` engine's SSI+SafeSnapshots mode and by tests to
 account reader-wait time — the cost RSS eliminates.
 """
 

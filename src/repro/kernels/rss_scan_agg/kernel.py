@@ -47,8 +47,8 @@ one group (its [8, 128] tile carries the row in sublane 0).
 
 Arithmetic intensity stays ~1 FLOP per K bytes read, but the fused path
 writes P/BP partial rows instead of P·E gathered elements and skips the
-host decode loop entirely — the win
-`benchmarks.bench_kernels.scan_agg_report` measures.
+host decode loop entirely.  Its time per plan on the chip is the
+benchmark's `scan_kernel_ms_per_query` (`bench/`).
 
 Three grouped strategies (shape-dispatched by `ops.select_grouped_mode`):
 

@@ -6,9 +6,9 @@ Dispatch (`select_grouped_mode`, flash-linear-attention's chunk /
 fused_recurrent idiom): small scans go "host" (launch overhead dominates
 — the mirror decodes and aggregates in Python), few groups go "flat"
 (all-G accumulator lanes per grid step), many groups go "chunked"
-(two-stage tiled-group reduction).  Thresholds come from
-`benchmarks.bench_kernels.group_agg_report` and are overridable — per
-call, or globally via the REPRO_GROUPED_MODE env var.
+(two-stage tiled-group reduction).  Thresholds come from a CPU
+interpret-mode sweep and have not been measured on the chip; they are
+overridable — per call, or globally via the REPRO_GROUPED_MODE env var.
 
 Overflow guard: device partials are int32.  The flat path only needs one
 BP-page block's partial to fit (|field| max * BP < 2**31).  BP is never
@@ -62,11 +62,11 @@ BLOCK_PAGES = 8                   # flat/scalar grid block: one sublane tile
 
 GROUPED_MODE_ENV = "REPRO_GROUPED_MODE"
 GROUPED_MODES = ("host", "flat", "chunked")
-# sweep-derived thresholds (benchmarks.bench_kernels.group_agg_report):
-# below HOST_MODE_MAX_PAGES the launch overhead beats any fusion win for a
-# single plan; flat-lane wins while all-G lanes still fit useful VMEM —
-# the measured flat/chunked crossover sits between G=32 and G=64 at
-# P=1024..4096.
+# thresholds from a CPU interpret-mode sweep, not yet measured on the
+# chip: below HOST_MODE_MAX_PAGES the launch overhead beat any fusion win
+# for a single plan; flat-lane won while all-G lanes still fit useful
+# VMEM — the interpreter's flat/chunked crossover sat between G=32 and
+# G=64 at P=1024..4096 (ROADMAP.md, D4).
 HOST_MODE_MAX_PAGES = 64
 FLAT_MODE_MAX_GROUPS = 32
 

@@ -1,4 +1,4 @@
-"""Deterministic logical-time workload driver for the HTAP benchmarks.
+"""Deterministic logical-time workload driver for the HTAP facades.
 
 Model: N clients run concurrently; in every *round* each client advances by
 exactly one step (one storage operation, one wait-poll, or one commit).  The
@@ -7,7 +7,7 @@ round counter is the logical clock, so a scan of 800 keys stays active for
 concurrency structure the paper's figures measure (writer-aborts under SSI,
 reader-waits under SafeSnapshots, neither under RSS).
 
-Throughput  = commits / rounds (per class), abort rate = aborts/(commits+aborts).
+Abort rate = aborts / (commits + aborts).
 """
 
 from __future__ import annotations
@@ -98,19 +98,9 @@ class Metrics:
     cache_store_hits: int = 0
     cache_store_misses: int = 0
 
-    def oltp_tps(self) -> float:
-        return self.oltp_commits / max(self.rounds, 1)
-
-    def olap_qps(self) -> float:
-        return self.olap_commits / max(self.rounds, 1)
-
     def oltp_abort_rate(self) -> float:
         d = self.oltp_commits + self.oltp_aborts
         return self.oltp_aborts / d if d else 0.0
-
-    def olap_abort_rate(self) -> float:
-        d = self.olap_commits + self.olap_aborts
-        return self.olap_aborts / d if d else 0.0
 
     def count_plan_step(self, plan) -> None:
         """Bump the per-plan-kind served-step counter."""

@@ -455,8 +455,8 @@ class CounterList(abc.Sequence):
 # ------------------------------------------------------- timing switch
 # Counters stay on unconditionally (one add each); timing instrumentation
 # (perf_counter pairs feeding latency histograms) flows through tick/tock
-# so the whole layer can be stubbed — the overhead bound in
-# benchmarks.bench_serve_latency compares default vs stubbed runs.
+# so the whole layer can be stubbed, e.g. to compare a run with and
+# without timing.
 _TIMING = [True]
 
 

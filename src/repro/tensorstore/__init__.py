@@ -1,6 +1,6 @@
-"""RSS-versioned tensor stores (the paper's technique at the ML boundary)."""
+"""RSS-versioned page stores: the paged mirror the OLAP kernels scan, the
+plan IR served over it, and incrementally maintained views."""
 
-from .versioned import VersionedParamStore
 from .paged import (init_store, visible_slots, snapshot_read_ref,
                     visible_slots_members, snapshot_read_members,
                     publish_page, as_page_range, gather_pages)
@@ -13,7 +13,6 @@ from .version_store import (AggOp, AggPlan, BatchPlan, ChainVersionStore,
                             plan_keys)
 
 __all__ = [
-    "VersionedParamStore",
     "init_store", "visible_slots", "snapshot_read_ref",
     "visible_slots_members", "snapshot_read_members", "publish_page",
     "as_page_range", "gather_pages",

@@ -1,9 +1,8 @@
-"""WAL / replica / versioned-store behaviour."""
+"""WAL / replica behaviour."""
 
 import pytest
 
 from repro.core import RSSManager, PRoTManager, Wal, WalRecord, replicate
-from repro.tensorstore import VersionedParamStore
 
 
 class TestWal:
@@ -232,30 +231,3 @@ class TestRSSManagerGC:
         assert m.is_member(3, snap2)
         assert m.stats["edges_pruned_pull"] == 1
         assert snap2.floor_seq >= snap.floor_seq      # floor is monotone
-
-
-class TestVersionedParamStore:
-    def test_wait_free_publish_under_pin(self):
-        store = VersionedParamStore(slots=2)
-        store.publish({"w": 1}); store.refresh()
-        pin, params = store.pin_snapshot()
-        assert params == {"w": 1}
-        # publisher keeps going; never blocks, ring may grow
-        for i in range(2, 6):
-            store.publish({"w": i})
-        _, params2 = store.pin_snapshot()
-        assert params2 == {"w": 1}            # watermark not refreshed yet
-        store.refresh()
-        _, params3 = store.pin_snapshot()
-        assert params3 == {"w": 5}
-        # the original pin still reads its version (no abort, no invalidation)
-        assert store.slots[store._pins[pin]].params == {"w": 1}
-
-    def test_freshness_lag_metric(self):
-        store = VersionedParamStore(slots=2)
-        store.publish({"w": 0}); store.refresh()
-        for i in range(3):
-            store.publish({"w": i})
-        assert store.freshness_lag() > 0
-        store.refresh()
-        assert store.freshness_lag() == 0
